@@ -1,7 +1,7 @@
 """MULTICHIP weak-scaling bench — the reduction-plan ranking story.
 
-Every BENCH_r0x number to date is ``devices: 1`` and MULTICHIP_r0x was a
-correctness dry-run only; this bench is the scale-out story: 3D Poisson
+MULTICHIP_r0x was a correctness dry-run only; this bench is the
+scale-out story: 3D Poisson
 stencil CG vs PIPELINED CG (1 reduce site/iteration) vs S-STEP CA-CG
 (1 site per s iterations, s ∈ {2, 4, 8} — solvers/cg_plans.py) across
 sub-meshes of 2/4/8 devices, published as MULTICHIP bench JSON with

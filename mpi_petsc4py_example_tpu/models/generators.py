@@ -12,9 +12,8 @@ from __future__ import annotations
 
 import numpy as np
 
-# scipy.sparse is imported inside each builder: it costs ~0.5 s of driver
-# start-up (BASELINE.md cfg2 floor decomposition) and the drivers that never
-# touch a CSR oracle shouldn't pay it
+# scipy.sparse is imported inside each builder: it costs driver start-up
+# time, and the drivers that never touch a CSR oracle shouldn't pay it
 
 
 def random_system(n: int = 100, seed: int = 42, density: float = 0.1):

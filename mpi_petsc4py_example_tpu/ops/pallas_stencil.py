@@ -31,11 +31,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _pipeline_depth() -> int:
     """Static DMA pipeline depth (banks per stream) for the z-chunk
-    kernels. Depth 2 (double buffering) is the measured default; the
+    kernels. Depth 2 (double buffering) is the default; the
     ``TPU_SOLVE_STENCIL_NBUF`` env knob exposes deeper pipelines (3-4) for
-    the DMA-plateau retuning sweeps (BASELINE.md 512³ table: the block-DMA
-    geometry, not compute, pins the stencil kernel at ~330 GB/s — a deeper
-    pipeline trades VMEM chunk depth for more DMAs in flight)."""
+    DMA retuning sweeps — a deeper pipeline trades VMEM chunk depth for
+    more DMAs in flight."""
     try:
         depth = int(os.environ.get("TPU_SOLVE_STENCIL_NBUF", "2"))
     except ValueError:
@@ -89,8 +88,7 @@ def _stencil_kernel(u_ref, lo_ref, hi_ref, out_ref, chunk, nchunks,
     with ONE wide contiguous HBM→VMEM copy of all ``chunk+2`` planes —
     round-6 DMA re-geometry: the 3-way split (center + two 1-plane edge
     copies) issued 3× the DMA descriptors for the same bytes, and the
-    1-plane edge copies are exactly the narrow transfers the measured
-    ~330 GB/s block-DMA plateau punishes (BASELINE.md 512³ table). Only the
+    1-plane edge copies are the narrowest transfers. Only the
     two boundary chunks still split, because their edge plane lives in a
     different array (the halo) than the center. All index/constant dtypes
     are pinned to i32/f32 explicitly: with x64 enabled, bare Python
@@ -289,10 +287,15 @@ def _stencil_kernel(u_ref, lo_ref, hi_ref, out_ref, chunk, nchunks,
 # 5.0-5.2; chunk=16 (96MB limit) 7.1 — more VMEM pressure hurts past
 # chunk 8, so half-of-VMEM capped at 64MB is the sweet spot.
 
-# physical VMEM per TensorCore by generation prefix of device_kind
-# (v2/v3: 16MB; v4 onward: 128MB — public TPU system architecture docs)
-_VMEM_BY_KIND = (("v2", 16 << 20), ("v3", 16 << 20))
-_VMEM_DEFAULT = 128 << 20
+# Physical VMEM per TensorCore, keyed by the exact ``device_kind`` JAX
+# reports. v5e ("TPU v5 lite"): 128 MiB — "How to Scale Your Model"
+# (jax-ml scaling book), TPU chapter; the v5e:2x2 described-chip compiles
+# in tests/test_chip_compile.py accept the 64 MiB limit planned from it.
+# A TPU generation missing here is an error, never a guessed size.
+_VMEM_BYTES = {"TPU v5 lite": 128 << 20}
+# interpret mode / CPU meshes (device_kind None) plan for the v5e part, so
+# host-side tests exercise the production chunk geometry
+_VMEM_HOST_PLAN = _VMEM_BYTES["TPU v5 lite"]
 
 
 @functools.lru_cache(maxsize=None)
@@ -301,22 +304,21 @@ def _vmem_plan(device_kind: str | None):
 
     The limit is half the physical VMEM capped at 64MB (the measured sweet
     spot on 128MB parts); the budget is 3/4 of the limit, leaving headroom
-    for Mosaic's own temporaries. On generations whose default limit
-    already equals the plan (16MB parts → 8MB request would only shrink
-    it) no explicit limit is requested and the chunk plan just adapts.
-    ``device_kind=None`` (interpret mode / CPU meshes) keeps the 128MB-part
-    plan so host-side tests exercise the production chunk geometry.
+    for Mosaic's own temporaries. A limit at or below Mosaic's ~16MB
+    default is not requested and the chunk plan just adapts.
+    ``device_kind=None`` (interpret mode / CPU meshes) keeps the v5e plan;
+    a TPU ``device_kind`` not in ``_VMEM_BYTES`` raises ``ValueError``.
     """
-    vmem = _VMEM_DEFAULT
-    if device_kind:
-        kl = device_kind.lower()
-        for tag, size in _VMEM_BY_KIND:
-            if tag in kl:
-                vmem = size
-                break
+    if device_kind is None:
+        vmem = _VMEM_HOST_PLAN
+    elif device_kind in _VMEM_BYTES:
+        vmem = _VMEM_BYTES[device_kind]
+    else:
+        raise ValueError(
+            f"no VMEM size known for TPU device_kind {device_kind!r}; add "
+            "it to ops/pallas_stencil._VMEM_BYTES with its source")
     limit = min(64 << 20, vmem // 2)
     budget = (limit * 3) // 4
-    # a limit at/below Mosaic's ~16MB default buys nothing — don't request
     return (limit if limit > (16 << 20) else None), budget
 
 
@@ -444,12 +446,17 @@ def _stencil_many_kernel(u_ref, lo_ref, hi_ref, out_ref, chunk, nchunks,
         cdt = _compute_dtype(out_ref.dtype)
         six = jnp.asarray(6.0, cdt)
         one = jnp.int32(1)
+        # column indices pinned to i32: with x64 on, a bare Python int
+        # index lowers as i64, which Mosaic's memref slices reject (found
+        # by the described-chip compile, tests/test_chip_compile.py)
+        zero = jnp.int32(0)
+        cols = [jnp.int32(j) for j in range(nrhs)]
         has_interior = nchunks >= 3
 
         def start_in(c, slot):
             z0 = c * jnp.int32(chunk)
             edge = (c == 0) | (c == nchunks - 1)
-            for j in range(nrhs):
+            for j in cols:
                 if has_interior:
                     @pl.when(~edge)
                     def _(j=j):
@@ -493,25 +500,25 @@ def _stencil_many_kernel(u_ref, lo_ref, hi_ref, out_ref, chunk, nchunks,
 
         def wait_in(c, slot):
             edge = (c == 0) | (c == nchunks - 1)
-            for j in range(nrhs):
+            for j in cols:
                 if has_interior:
                     @pl.when(~edge)
                     def _(j=j):
                         pltpu.make_async_copy(
-                            u_ref.at[0, pl.ds(0, chunk + 2)], sc.at[slot, j],
+                            u_ref.at[zero, pl.ds(0, chunk + 2)], sc.at[slot, j],
                             sem_c.at[slot, j]).wait()
 
                 @pl.when(edge)
                 def _(j=j):
                     pltpu.make_async_copy(
-                        u_ref.at[0, pl.ds(0, chunk)],
+                        u_ref.at[zero, pl.ds(0, chunk)],
                         sc.at[slot, j, pl.ds(one, chunk)],
                         sem_c.at[slot, j]).wait()
                     pltpu.make_async_copy(
-                        lo_ref.at[0], sc.at[slot, j, pl.ds(0, 1)],
+                        lo_ref.at[zero], sc.at[slot, j, pl.ds(0, 1)],
                         sem_lo.at[slot, j]).wait()
                     pltpu.make_async_copy(
-                        hi_ref.at[0],
+                        hi_ref.at[zero],
                         sc.at[slot, j, pl.ds(jnp.int32(chunk + 1), 1)],
                         sem_hi.at[slot, j]).wait()
 
@@ -531,7 +538,7 @@ def _stencil_many_kernel(u_ref, lo_ref, hi_ref, out_ref, chunk, nchunks,
 
             wait_in(c, slot)
             parts = []
-            for j in range(nrhs):
+            for j in cols:
                 buf = sc[slot, j].astype(cdt)
                 u = buf[1:-1]
                 y = (six * u - buf[:-2] - buf[2:]
@@ -559,18 +566,18 @@ def _stencil_many_kernel(u_ref, lo_ref, hi_ref, out_ref, chunk, nchunks,
         acc = jax.lax.fori_loop(jnp.int32(0), jnp.int32(nchunks), body,
                                 carry0)
         if dot_ref is not None:
-            for j in range(nrhs):
+            for j in range(nrhs):       # static: acc is a value, not a ref
                 dot_ref[j] = acc[j]
         last = jnp.int32(nchunks - 1)
         for d in range(nbuf - 1, 0, -1):
-            for j in range(nrhs):
+            for j in cols:
                 @pl.when(jnp.int32(nchunks) >= d + 1)
                 def _(d=d, j=j):
                     pltpu.make_async_copy(
                         osc.at[lax_rem(last - jnp.int32(d)), j],
                         out_ref.at[j, pl.ds(0, chunk)],
                         sem_out.at[lax_rem(last - jnp.int32(d)), j]).wait()
-        for j in range(nrhs):
+        for j in cols:
             pltpu.make_async_copy(
                 osc.at[lax_rem(last), j], out_ref.at[j, pl.ds(0, chunk)],
                 sem_out.at[lax_rem(last), j]).wait()

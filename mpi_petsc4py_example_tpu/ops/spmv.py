@@ -35,18 +35,44 @@ def accum_dtype(dtype):
     return jnp.float32 if is_low_precision(dtype) else None
 
 
-def widened_einsum(spec, a, b):
+def widened_einsum(spec, a, b, platform: str | None = None):
     """``jnp.einsum(spec, a, b)`` with the accumulation discipline of
     :func:`accum_dtype` applied once: sub-f32 operand storage contracts
     with ``preferred_element_type=f32`` and the result returns to the
     first operand's storage dtype; everything else is the plain einsum.
     The ONE definition the SpMV kernels and the PC factor applies
     (solvers/pc.py bjacobi/lu, single- and multi-RHS) all share — a
-    future accumulation-policy change edits exactly one site."""
+    future accumulation-policy change edits exactly one site.
+
+    On a TPU mesh (``platform="tpu"``) f64/c128 operands contract as a
+    fused multiply + reduce instead: XLA:TPU emulates an f64 dot by
+    materializing split copies of the operands, 24 GiB of temporaries for
+    the 4 GiB bjacobi stack of convdiff2d(512) on one v5e (sandbox
+    compile), where the fused reduce streams the factor once."""
+    if platform == "tpu" and jnp.dtype(a.dtype) in (jnp.float64,
+                                                    jnp.complex128):
+        return _multiply_reduce(spec, a, b)
     acc = accum_dtype(a.dtype)
     if acc is None:
         return jnp.einsum(spec, a, b)
     return jnp.einsum(spec, a, b, preferred_element_type=acc).astype(a.dtype)
+
+
+def _multiply_reduce(spec, a, b):
+    """``einsum(spec, a, b)`` as broadcast multiply + sum over the
+    contracted letters (no dot_general)."""
+    ins, out = spec.split("->")
+    sa, sb = ins.split(",")
+    letters = sorted(set(sa + sb), key=lambda c: (c not in out, c))
+
+    def expand(x, sx):
+        x = jnp.transpose(x, [sx.index(c) for c in letters if c in sx])
+        return x.reshape([x.shape[[c for c in letters if c in sx].index(c)]
+                          if c in sx else 1 for c in letters])
+
+    prod = expand(a, sa) * expand(b, sb)
+    red = prod.sum(axis=tuple(range(len(out), len(letters))))
+    return jnp.transpose(red, [sorted(out).index(c) for c in out])
 
 
 def csr_to_ell(indptr, indices, data, ncols_pad_to: int | None = None):

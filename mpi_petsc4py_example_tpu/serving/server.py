@@ -1,9 +1,8 @@
 """SolveServer — a persistent, device-resident solve session.
 
-The bench data (BENCH_r05 / ROADMAP item 1) shows the on-chip CG loop at
-~35k iters/s while an end-to-end solve spends ~95% of its wall in
-per-request dispatch/launch latency. The serving answer is to stop
-paying that latency per request: a long-lived :class:`SolveServer`
+Where an end-to-end solve spends most of its wall in per-request
+dispatch/launch latency, the serving answer is to stop paying that
+latency per request: a long-lived :class:`SolveServer`
 session registers each operator ONCE — CSR/ELL/DIA operands, PC
 factors, and the AOT-cached compiled programs stay resident in device
 HBM — and a concurrent stream of solve requests is COALESCED into

@@ -1,8 +1,7 @@
 """Megasolve — whole-solve fusion: one dispatch per request (ROADMAP 3a).
 
-BENCH_r05 measures the on-chip CG loop at ~35k iters/s (~6.5 ms of
-device work for a 227-iteration solve) inside a ~0.12 s end-to-end wall:
-after AOT caching, what remains is per-PHASE dispatch. ``RefinedKSP``
+After AOT caching, what remains of a short solve's end-to-end wall is
+per-PHASE dispatch. ``RefinedKSP``
 drives its outer Wilkinson recurrence from the HOST — the inner
 low-precision solve, the fp64 true residual, the correction AXPY, and
 the epilogue re-verification each cost a separate compiled-program

@@ -1,9 +1,8 @@
 """AOT program export/deserialize — fresh-process cold-start cutter.
 
-Round-6 perf lever (VERDICT weak #5): cfg2's fresh-process wall spends
-2.79 s of 5.76 s in the eigensolve phase, dominated by re-tracing and
-compile-cache-loading the two fixed-shape EPS programs (seed+facto and
-compress+facto — BASELINE.md cfg2 decomposition). The XLA compilation
+A fresh driver process (the test2.py flow) spends its eigensolve phase
+re-tracing and compile-cache-loading the two fixed-shape EPS programs
+(seed+facto and compress+facto). The XLA compilation
 cache only helps a warm *machine*; a fresh process still pays the full
 Python trace + lowering for each program.
 
@@ -16,7 +15,7 @@ compose.
 
 Cache layout: one ``<sha256>.jaxexport`` blob per (program kind, program
 key, mesh topology, jax version) under ``TPU_SOLVE_AOT_DIR`` (default
-``~/.cache/tpu_solve/aot``). Writes are atomic (tmp + ``os.replace``, the
+``<checkout>/.tpu_solve_cache/aot``, a fixed path like the XLA cache's). Writes are atomic (tmp + ``os.replace``, the
 checkpoint.py discipline). Every load/export failure falls back silently
 to the traced program — AOT is an optimization, never a correctness
 dependency. ``TPU_SOLVE_AOT=0`` disables the whole path.
@@ -61,8 +60,8 @@ def aot_enabled() -> bool:
 def cache_dir() -> str:
     d = os.environ.get("TPU_SOLVE_AOT_DIR")
     if not d:
-        d = os.path.join(os.path.expanduser("~"), ".cache", "tpu_solve",
-                         "aot")
+        from .. import CHECKOUT_DIR
+        d = os.path.join(CHECKOUT_DIR, ".tpu_solve_cache", "aot")
     return d
 
 

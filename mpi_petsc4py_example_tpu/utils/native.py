@@ -1,17 +1,25 @@
 """ctypes loader for the native C++ CSR toolkit (native/csrkit.cpp).
 
-Compiles the shared library on first use (g++ -O3) and caches it under
-``native/build/``. Every entry point has a vectorized-numpy fallback, so the
-framework works without a toolchain; the native path matters for large
-operators (100M-DoF assembly) where Python-level passes dominate.
+Compiles the shared library on first use (g++ -O3 -march=native) into
+``native/build/``, under a name keyed on a hash of the source, the flags
+and this host's CPU features: a library built from other source, with
+other flags or on another CPU (a working tree copied to another machine)
+is never loaded — it is rebuilt. Every entry point has a vectorized-numpy
+fallback, so the framework works without a toolchain; a failed build or
+load warns once and :func:`status` says which path runs. The native path
+matters for large operators (100M-DoF assembly) where Python-level passes
+dominate.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import tempfile
 import threading
+import warnings
 
 import numpy as np
 
@@ -19,11 +27,12 @@ _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO, "native", "csrkit.cpp")
 _BUILD_DIR = os.path.join(_REPO, "native", "build")
-_SO = os.path.join(_BUILD_DIR, "libcsrkit.so")
+_FLAGS = ("-O3", "-march=native", "-shared", "-fPIC")
 
 _lock = threading.Lock()
 _lib = None
 _lib_tried = False
+_status = "not tried"
 
 _I64 = ctypes.POINTER(ctypes.c_int64)
 _I32 = ctypes.POINTER(ctypes.c_int32)
@@ -34,45 +43,69 @@ def _as(arr, ptr_t):
     return arr.ctypes.data_as(ptr_t)
 
 
-def _compile() -> str | None:
-    if not os.path.exists(_SRC):
-        return None
+def build_key(src: bytes) -> str:
+    """Hash of the source, the compiler flags and this host's CPU
+    features — the name a matching library is built under."""
+    from .aot import host_machine_fingerprint
+    h = hashlib.sha256(src)
+    h.update(" ".join(_FLAGS).encode())
+    h.update(host_machine_fingerprint().encode())
+    return h.hexdigest()[:16]
+
+
+def _compile() -> str:
+    """Path of the library built from this source, with these flags, for
+    this host — building it if absent. Raises on a failed build."""
+    with open(_SRC, "rb") as fh:
+        so = os.path.join(_BUILD_DIR,
+                          f"libcsrkit-{build_key(fh.read())}.so")
+    if os.path.exists(so):
+        return so
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    if os.path.exists(_SO) and \
-            os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return _SO
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-           "-o", _SO, _SRC]
+    fd, tmp = tempfile.mkstemp(dir=_BUILD_DIR, suffix=".so.tmp")
+    os.close(fd)
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return _SO
-    except (subprocess.SubprocessError, FileNotFoundError, OSError):
-        return None
+        subprocess.run(["g++", *_FLAGS, "-o", tmp, _SRC], check=True,
+                       capture_output=True, timeout=120)
+        os.replace(tmp, so)      # atomic: concurrent builders agree
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    return so
 
 
 def get_lib():
     """The loaded native library, or None if unavailable."""
-    global _lib, _lib_tried
+    global _lib, _lib_tried, _status
     with _lock:
         if _lib is None and not _lib_tried:
             _lib_tried = True
-            so = _compile()
-            if so:
-                try:
-                    lib = ctypes.CDLL(so)
-                    lib.csr_validate.restype = ctypes.c_int
-                    lib.csr_max_row_nnz.restype = ctypes.c_int64
-                    lib.csr_aggregate.restype = ctypes.c_int64
-                    _lib = lib
-                except (OSError, AttributeError):
-                    # AttributeError: stale .so missing a newer symbol —
-                    # fall back to numpy rather than crash assembly
-                    _lib = None
+            try:
+                so = _compile()
+                lib = ctypes.CDLL(so)
+                lib.csr_validate.restype = ctypes.c_int
+                lib.csr_max_row_nnz.restype = ctypes.c_int64
+                lib.csr_aggregate.restype = ctypes.c_int64
+                _lib = lib
+                _status = f"loaded {os.path.basename(so)}"
+            except (subprocess.SubprocessError, OSError,
+                    AttributeError) as e:
+                _status = f"numpy fallback ({type(e).__name__}: {e})"
+                warnings.warn(f"native CSR toolkit unavailable, using the "
+                              f"numpy path: {e}", RuntimeWarning,
+                              stacklevel=2)
         return _lib
 
 
 def available() -> bool:
     return get_lib() is not None
+
+
+def status() -> str:
+    """Which path the CSR toolkit takes: ``loaded <lib>`` or ``numpy
+    fallback (<why>)``."""
+    get_lib()
+    return _status
 
 
 def _prep(indptr, indices, data):

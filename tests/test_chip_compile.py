@@ -1,0 +1,131 @@
+"""Described-chip compiles of the main-path Pallas kernels at real widths.
+
+Each test compiles one kernel for a v5e chip that is described, not
+attached (``jax.experimental.topologies``), and checks what interpret mode
+cannot: that Mosaic accepts the kernel (VMEM limit, aligned chunk slices)
+— a ``tpu_custom_call`` in the compiled program — and that the program
+fits one chip's 16 GB of HBM. Nothing runs, so nothing here says anything
+about results or times.
+
+The topology is described inside a module fixture (never at import): only
+one process at a time may load the TPU library, and the xdist worker that
+runs this file is that process. The persistent compilation cache is off
+around these compiles (a described-chip entry cannot be read back here).
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+HBM_BYTES = 16 * 10 ** 9            # one v5e: 16 GB of HBM
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiled(fn, args, one_chip):
+    """Compile ``fn(*args)`` for the described chip: arrays given as
+    ``(shape, dtype)`` become placed shapes, everything else is static."""
+    spec = [jax.ShapeDtypeStruct(a[0], a[1], sharding=one_chip)
+            if isinstance(a, tuple) else a for a in args]
+    compiled = fn.lower(*spec).compile()
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert used < HBM_BYTES, used
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+F32 = jnp.float32
+
+
+def _slab(lz, n, k=None):
+    lead = () if k is None else (k,)
+    return (lead + (lz, n, n), F32), (lead + (1, n, n), F32)
+
+
+@pytest.mark.parametrize("n", [256, 512])
+def test_apply(one_chip, n):
+    from mpi_petsc4py_example_tpu.ops.pallas_stencil import (
+        stencil3d_apply_pallas)
+    u, plane = _slab(n, n)
+    _compiled(stencil3d_apply_pallas, [u, plane, plane, n, n, n], one_chip)
+
+
+@pytest.mark.parametrize("n", [256, 512])
+def test_fused_dot(one_chip, n):
+    """The CG hot loop's fused stencil + <p, Ap> kernel."""
+    from mpi_petsc4py_example_tpu.ops.pallas_stencil import (
+        stencil3d_dot_pallas)
+    u, plane = _slab(n, n)
+    _compiled(stencil3d_dot_pallas, [u, plane, plane, n, n, n], one_chip)
+
+
+def test_mg_smoother(one_chip):
+    from mpi_petsc4py_example_tpu.ops.pallas_stencil import (
+        stencil3d_smooth_pallas)
+    from mpi_petsc4py_example_tpu.solvers.mg import _OMEGA
+    u, plane = _slab(256, 256)
+    _compiled(stencil3d_smooth_pallas,
+              [u, u, plane, plane, 256, 256, 256, _OMEGA / 6.0], one_chip)
+
+
+def test_mg_smoother_pair(one_chip):
+    from mpi_petsc4py_example_tpu.ops.pallas_stencil import (
+        stencil3d_smooth_pair_pallas)
+    u, _ = _slab(256, 256)
+    _compiled(stencil3d_smooth_pair_pallas,
+              [u, u, 256, 256, 256, 0.1, 0.1], one_chip)
+
+
+def test_mg_residual_restrict(one_chip):
+    """The fused residual + full 3-axis restriction of the V-cycle's
+    finest single-device level."""
+    from mpi_petsc4py_example_tpu.ops.pallas_stencil import (
+        stencil3d_residual_restrict_pallas)
+    from mpi_petsc4py_example_tpu.solvers.mg import _RSCALE, _tmat
+    u, _ = _slab(256, 256)
+    wyt = np.asarray(_tmat(256, np.float32)).T
+    _compiled(stencil3d_residual_restrict_pallas,
+              [u, u, (wyt.shape, F32), ((256, 128), F32), 256, 256, 256,
+               _RSCALE], one_chip)
+
+
+def test_batched_apply_k8(one_chip):
+    from mpi_petsc4py_example_tpu.ops.pallas_stencil import (
+        stencil3d_apply_many_pallas)
+    u, plane = _slab(128, 256, k=8)
+    _compiled(stencil3d_apply_many_pallas,
+              [u, plane, plane, 128, 256, 256, 8], one_chip)
+
+
+def test_batched_fused_dot_k8(one_chip):
+    """The batched stencil-CG fast path (served blocks of k requests)."""
+    from mpi_petsc4py_example_tpu.ops.pallas_stencil import (
+        stencil3d_dot_many_pallas)
+    u, plane = _slab(128, 256, k=8)
+    _compiled(stencil3d_dot_many_pallas,
+              [u, plane, plane, 128, 256, 256, 8], one_chip)

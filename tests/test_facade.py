@@ -262,7 +262,7 @@ class TestSLEPcFacade:
 
 def run_driver(script, nranks, extra=()):
     env = dict(os.environ)
-    env["TPU_SOLVE_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count=8").strip()
     cmd = [sys.executable, os.path.join(REPO, "tools", "tpurun.py"),
@@ -313,7 +313,7 @@ class TestLiteralReferenceDrivers:
 
     def run_reference(self, script, nranks):
         env = dict(os.environ)
-        env["TPU_SOLVE_PLATFORM"] = "cpu"
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                             " --xla_force_host_platform_device_count=8").strip()
         cmd = [sys.executable, os.path.join(REPO, "tools", "tpurun.py"),

@@ -198,16 +198,24 @@ def test_fast_path_gates_key_on_mesh_platform(monkeypatch):
 
 
 def test_vmem_plan_per_generation():
-    """ADVICE r4: the Mosaic VMEM limit/budget derive from the device
-    generation — 16MB parts must not be asked for a 64MB limit."""
+    """The Mosaic VMEM limit/budget derive from the named generation's
+    physical VMEM (v5e: 128 MiB -> 64 MiB limit, 48 MiB budget)."""
     from mpi_petsc4py_example_tpu.ops.pallas_stencil import _vmem_plan
 
-    limit, budget = _vmem_plan("TPU v5e")
+    limit, budget = _vmem_plan("TPU v5 lite")
     assert limit == 64 << 20 and budget == 48 << 20
-    limit, budget = _vmem_plan("TPU v3")
-    assert limit is None and budget == 6 << 20
     limit, budget = _vmem_plan(None)        # CPU/interpret: production plan
     assert limit == 64 << 20 and budget == 48 << 20
+
+
+@pytest.mark.parametrize("kind", ["TPU v3", "TPU v6 lite", "TPU v5e"])
+def test_vmem_plan_unknown_device_kind_raises(kind):
+    """A TPU device_kind without a known VMEM size is an error, never a
+    guessed default."""
+    from mpi_petsc4py_example_tpu.ops.pallas_stencil import _vmem_plan
+
+    with pytest.raises(ValueError, match="no VMEM size known"):
+        _vmem_plan(kind)
 
 
 @pytest.mark.parametrize("lz,ny,nx,max_chunk", [

@@ -27,8 +27,10 @@ def _blocks_of(pc_obj):
     return np.asarray(pc_obj._arrays[0])
 
 
-def _built_bjacobi(comm, A, dtype, setup_device, blocks=0):
+def _built_bjacobi(comm, A, dtype, setup_device, blocks=0, ell=True):
     M = tps.Mat.from_scipy(comm, sp.csr_matrix(A, dtype=dtype))
+    if not ell:
+        M.ell_cols = None     # no device-resident ELL: host block extraction
     p = tps.PC(comm)
     p.set_type("bjacobi")
     p.bjacobi_blocks = blocks
@@ -186,18 +188,13 @@ class TestGateFallback:
         """A rejected device inversion after HOST block extraction falls
         back to LAPACK over the already-extracted dense stack — same
         numbers as the pure host path, setup_mode 'host'. (ELL extraction
-        is disabled so the host-extract + dense-reuse branch is the one
+        is unavailable so the host-extract + dense-reuse branch is the one
         under test.)"""
         monkeypatch.setattr(pcmod, "_device_inverse_blocks",
                             lambda comm, blocks: None)
-
-        def boom(*a, **k):
-            raise RuntimeError("forced: no device extraction")
-
-        monkeypatch.setattr(pcmod, "_ell_diag_blocks", boom)
         A = convdiff2d(16)
         ph = _built_bjacobi(comm8, A, np.float64, "0")
-        pf = _built_bjacobi(comm8, A, np.float64, "1")   # forced, rejected
+        pf = _built_bjacobi(comm8, A, np.float64, "1", ell=False)  # rejected
         assert pf.setup_mode == "host"
         np.testing.assert_allclose(_blocks_of(pf), _blocks_of(ph),
                                    rtol=1e-12, atol=1e-12)
@@ -213,6 +210,26 @@ class TestGateFallback:
         assert pf.setup_mode == "host"
         np.testing.assert_allclose(_blocks_of(pf), _blocks_of(ph),
                                    rtol=1e-12, atol=1e-12)
+
+    def test_gate_rejection_is_counted(self, comm8):
+        """A singular block fails the device quality gate; the rejection
+        is counted in ``gate_fallbacks`` before the host path runs."""
+        d = np.ones(64)
+        d[10] = 0.0
+        before = pcmod.gate_fallbacks["block"]
+        with pytest.raises(Exception, match="[Ss]ingular"):
+            _built_bjacobi(comm8, sp.diags(d).tocsr(), np.float64, "1")
+        assert pcmod.gate_fallbacks["block"] == before + 1
+
+    def test_device_error_propagates(self, comm8, monkeypatch):
+        """A compile/runtime error of the device inversion is raised, not
+        hidden behind the host LAPACK path."""
+        def boom(B):
+            raise RuntimeError("forced: device program failed")
+
+        monkeypatch.setattr(pcmod, "_inv_polish", boom)
+        with pytest.raises(RuntimeError, match="forced"):
+            _built_bjacobi(comm8, convdiff2d(16), np.float64, "1")
 
     def test_singular_block_raises_proper_error(self, comm8):
         """End-to-end: device gate rejects a singular block and the host

@@ -6,7 +6,10 @@ Usage::
     python tools/tpurun.py -n 4 driver.py [driver args...]
 
 Spawns N threads, each executing ``driver.py`` as ``__main__`` with a
-thread-local MPI rank (compat/mpi4py). Point-to-point sends/recvs and
+thread-local MPI rank (compat/mpi4py). :func:`main` takes an argument list,
+so a process that already holds the chip (chip_smoke.py,
+benchmarks/run_all.py) runs a driver in-process rather than in a child
+that would need the same chip. Point-to-point sends/recvs and
 collectives rendezvous in-process; device work (assembly, KSP/EPS solves)
 executes once on the rank-0 thread over the full device mesh. This is the
 TPU analog of the reference's oversubscribed ``mpirun -n N python test.py``
@@ -23,14 +26,25 @@ import threading
 import traceback
 
 
-def main():
+def main(argv=None):
+    """Run a driver script under ``-n`` virtual ranks; returns the exit
+    code (1 when any rank raised). ``argv`` defaults to ``sys.argv[1:]``;
+    ``sys.argv`` and ``sys.path`` are restored afterwards."""
+    saved = sys.argv[:], sys.path[:]
+    try:
+        return _run(argv)
+    finally:
+        sys.argv[:], sys.path[:] = saved
+
+
+def _run(argv):
     ap = argparse.ArgumentParser(prog="tpurun", add_help=True)
     ap.add_argument("-n", "--np", type=int, default=1,
                     help="number of virtual ranks (threads)")
     ap.add_argument("script", help="driver script to run")
     ap.add_argument("args", nargs=argparse.REMAINDER,
                     help="arguments passed to the driver")
-    opts = ap.parse_args()
+    opts = ap.parse_args(argv)
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     compat = os.path.join(repo, "compat")
