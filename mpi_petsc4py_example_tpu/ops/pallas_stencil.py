@@ -16,6 +16,12 @@ Dirichlet boundaries in x/y are realized by shifting with zero fill inside
 the kernel.
 
 Falls back to the pure-jnp path on non-TPU backends (models/stencil.py).
+
+Every ``pallas_call`` passes ``name=``: the custom call's name in the HLO
+and so in the device trace, the kernel function's own name. The
+multigrid kernels take a static ``name`` (that name by default), and
+solvers/mg.py adds the level (``stencil3d_smooth_pair_pallas_l0``), so a
+trace splits the V-cycle's kernel time by level.
 """
 
 from __future__ import annotations
@@ -387,6 +393,7 @@ def stencil3d_apply_pallas(u, halo_lo, halo_hi, lz: int, ny: int, nx: int,
         out_shape=jax.ShapeDtypeStruct((lz, ny, nx), u.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        name="stencil3d_apply_pallas",
         compiler_params=_vmem_limit_params(interpret),
         interpret=interpret,
     )(u, halo_lo, halo_hi)
@@ -423,6 +430,7 @@ def stencil3d_dot_pallas(u, halo_lo, halo_hi, lz: int, ny: int, nx: int,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
         out_specs=(pl.BlockSpec(memory_space=pl.ANY),
                    pl.BlockSpec(memory_space=pltpu.SMEM)),
+        name="stencil3d_dot_pallas",
         compiler_params=_vmem_limit_params(interpret),
         interpret=interpret,
     )(u, halo_lo, halo_hi)
@@ -618,6 +626,7 @@ def stencil3d_apply_many_pallas(u, halo_lo, halo_hi, lz: int, ny: int,
         out_shape=jax.ShapeDtypeStruct((nrhs, lz, ny, nx), u.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        name="stencil3d_apply_many_pallas",
         compiler_params=_vmem_limit_params(interpret),
         interpret=interpret,
     )(u, halo_lo, halo_hi)
@@ -649,17 +658,20 @@ def stencil3d_dot_many_pallas(u, halo_lo, halo_hi, lz: int, ny: int,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
         out_specs=(pl.BlockSpec(memory_space=pl.ANY),
                    pl.BlockSpec(memory_space=pltpu.SMEM)),
+        name="stencil3d_dot_many_pallas",
         compiler_params=_vmem_limit_params(interpret),
         interpret=interpret,
     )(u, halo_lo, halo_hi)
     return y, dot
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9),
+                   static_argnames=("name",))
 def stencil3d_smooth_pallas(u, f, halo_lo, halo_hi, lz: int, ny: int,
                             nx: int, omega6: float,
                             interpret: bool = False,
-                            max_chunk: int | None = None):
+                            max_chunk: int | None = None,
+                            *, name: str | None = None):
     """One damped-Jacobi sweep in ONE streamed pass:
     ``u + omega6*(f - A u)``.
 
@@ -684,15 +696,18 @@ def stencil3d_smooth_pallas(u, f, halo_lo, halo_hi, lz: int, ny: int,
         out_shape=jax.ShapeDtypeStruct((lz, ny, nx), u.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 4,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        name=name or "stencil3d_smooth_pallas",
         compiler_params=_vmem_limit_params(interpret),
         interpret=interpret,
     )(u, halo_lo, halo_hi, f)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8),
+                   static_argnames=("name",))
 def stencil3d_residual_pallas(u, f, halo_lo, halo_hi, lz: int, ny: int,
                               nx: int, interpret: bool = False,
-                              max_chunk: int | None = None):
+                              max_chunk: int | None = None,
+                              *, name: str | None = None):
     """Residual in ONE streamed pass: ``f - A u`` (the V-cycle's
     pre-restriction residual; same fusion rationale as the smooth sweep)."""
     chunk, nchunks = _pick_chunk(lz, u.dtype.itemsize, ny, nx, max_chunk,
@@ -709,6 +724,7 @@ def stencil3d_residual_pallas(u, f, halo_lo, halo_hi, lz: int, ny: int,
         out_shape=jax.ShapeDtypeStruct((lz, ny, nx), u.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 4,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        name=name or "stencil3d_residual_pallas",
         compiler_params=_vmem_limit_params(interpret),
         interpret=interpret,
     )(u, halo_lo, halo_hi, f)
@@ -968,11 +984,13 @@ def _resid_zrestrict_kernel(u_ref, f_ref, out_ref, chunk, nchunks, rscale):
                                            out_ref.dtype))
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7))
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7),
+                   static_argnames=("name",))
 def stencil3d_residual_zrestrict_pallas(u, f, lz: int, ny: int, nx: int,
                                         rscale: float,
                                         interpret: bool = False,
-                                        max_chunk: int | None = None):
+                                        max_chunk: int | None = None,
+                                        *, name: str | None = None):
     """Fused residual + one-axis z-restriction for SINGLE-DEVICE slabs:
     ``zrestrict(f - A u)`` with solvers/mg._r1d's weights and zero ghosts,
     returning the (lz/2, ny, nx) coarse array without ever writing the
@@ -986,6 +1004,7 @@ def stencil3d_residual_zrestrict_pallas(u, f, lz: int, ny: int, nx: int,
         out_shape=jax.ShapeDtypeStruct((lz // 2, ny, nx), u.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        name=name or "stencil3d_residual_zrestrict_pallas",
         compiler_params=_vmem_limit_params(interpret),
         interpret=interpret,
     )(u, f)
@@ -1093,11 +1112,13 @@ def _resid_restrict3_kernel(u_ref, f_ref, wyt_ref, wx_ref, out_ref, chunk,
     pl.run_scoped(process, *scratch)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9),
+                   static_argnames=("name",))
 def stencil3d_residual_restrict_pallas(u, f, wyt, wx, lz: int, ny: int,
                                        nx: int, rscale: float,
                                        interpret: bool = False,
-                                       max_chunk: int | None = None):
+                                       max_chunk: int | None = None,
+                                       *, name: str | None = None):
     """Fused residual + FULL 3-axis restriction for SINGLE-DEVICE slabs:
     ``restrict(f - A u)`` with solvers/mg's transfer weights and zero
     ghosts, returning the (lz/2, ny/2, nx/2) coarse RHS without the fine
@@ -1122,16 +1143,19 @@ def stencil3d_residual_restrict_pallas(u, f, wyt, wx, lz: int, ny: int,
                   pl.BlockSpec(memory_space=pltpu.VMEM),
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        name=name or "stencil3d_residual_restrict_pallas",
         compiler_params=_vmem_limit_params(interpret),
         interpret=interpret,
     )(u, f, wyt, wx)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7))
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7),
+                   static_argnames=("name",))
 def stencil3d_smooth0_pair_pallas(f, lz: int, ny: int, nx: int,
                                   w1: float, w2: float,
                                   interpret: bool = False,
-                                  max_chunk: int | None = None):
+                                  max_chunk: int | None = None,
+                                  *, name: str | None = None):
     """TWO damped-Jacobi sweeps from a ZERO initial guess in ONE streamed
     pass (round 5; single-device slabs, zero Dirichlet ghosts):
 
@@ -1156,6 +1180,7 @@ def stencil3d_smooth0_pair_pallas(f, lz: int, ny: int, nx: int,
         out_shape=jax.ShapeDtypeStruct((lz, ny, nx), f.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        name=name or "stencil3d_smooth0_pair_pallas",
         compiler_params=_vmem_limit_params(interpret),
         interpret=interpret,
     )(f, z, z)
@@ -1254,11 +1279,13 @@ def _double_sweep_kernel(u_ref, f_ref, out_ref, chunk, nchunks, w1, w2):
                                            out_ref.dtype))
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 8))
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 8),
+                   static_argnames=("name",))
 def stencil3d_smooth_pair_pallas(u, f, lz: int, ny: int, nx: int,
                                  w1: float, w2: float,
                                  interpret: bool = False,
-                                 max_chunk: int | None = None):
+                                 max_chunk: int | None = None,
+                                 *, name: str | None = None):
     """Two damped-Jacobi sweeps from a NONZERO guess in one streamed pass
     (see _double_sweep_kernel). ``w1``/``w2`` are the sweeps' ω/6.
 
@@ -1284,6 +1311,7 @@ def stencil3d_smooth_pair_pallas(u, f, lz: int, ny: int, nx: int,
         out_shape=jax.ShapeDtypeStruct((lz, ny, nx), u.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        name=name or "stencil3d_smooth_pair_pallas",
         compiler_params=_vmem_limit_params(interpret),
         interpret=interpret,
     )(u, f)

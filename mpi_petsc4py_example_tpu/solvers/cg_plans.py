@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -439,65 +440,82 @@ def classic_cg_loop(*, b, x0, rtol, atol, maxit, dtol=None,
         it, x, r, p, rz = st["it"], st["x"], st["r"], st["p"], st["rz"]
 
         # ---- operator apply + reduction phase 1 ----
+        # (each phase in a jax.named_scope: cg.apply, cg.dot, cg.update,
+        # cg.pc, which the HLO's op_name metadata carries)
         if stencil:
-            Ap, pAp = Adot(p)                  # fused matvec+dot (1 psum)
+            with jax.named_scope("cg.apply"):
+                Ap, pAp = Adot(p)              # fused matvec+dot (1 psum)
             badA = None
-        elif g is not None:
-            Ap = A(p)
-            pAp, badA = g.p1(p, Ap)            # stacked phase 1 + A-ABFT
         else:
-            Ap = A(p)
-            pAp = pdot(p, Ap)                  # reduction phase 1
-            badA = None
-        brk_new = cont & (pAp == 0)
-        alpha = jnp.where(pAp == 0, 0.0,
-                          rz / jnp.where(pAp == 0, 1.0, pAp))
-        # frozen steps/columns SELECT the old state rather than multiplying
-        # by a zero gate: once a diverging active step has produced
-        # inf/NaN, 0 * inf = NaN would destroy the preserved iterate
-        al = bp.ex(alpha)
-        x = jnp.where(cm, st_(x + al * p), x)
-        r = jnp.where(cm, st_(r - al * Ap), r)
+            with jax.named_scope("cg.apply"):
+                Ap = A(p)
+            with jax.named_scope("cg.dot"):
+                if g is not None:
+                    pAp, badA = g.p1(p, Ap)    # stacked phase 1 + A-ABFT
+                else:
+                    pAp = pdot(p, Ap)          # reduction phase 1
+                    badA = None
+        with jax.named_scope("cg.update"):
+            brk_new = cont & (pAp == 0)
+            alpha = jnp.where(pAp == 0, 0.0,
+                              rz / jnp.where(pAp == 0, 1.0, pAp))
+            # frozen steps/columns SELECT the old state rather than
+            # multiplying by a zero gate: once a diverging active step
+            # has produced inf/NaN, 0 * inf = NaN would destroy the
+            # preserved iterate
+            al = bp.ex(alpha)
+            x = jnp.where(cm, st_(x + al * p), x)
+            r = jnp.where(cm, st_(r - al * Ap), r)
 
         # ---- PC apply + reduction phase 2 ----
         z = None
         badM = None
         if stencil:
             if g is not None:
-                rr, badA = g.p2_stencil(r, p, Ap)   # fused phase 2 + ABFT
+                with jax.named_scope("cg.dot"):
+                    rr, badA = g.p2_stencil(r, p, Ap)  # phase 2 + ABFT
                 rz_new = rr * inv_diag
                 zdir = st_(r * inv_diag)
                 rn_new = jnp.sqrt(rr)
             elif M3 is not None:
-                rr = pdot(r, r)
-                zn = M3(r)
-                rz_new = pdot(r, zn)
+                with jax.named_scope("cg.dot"):
+                    rr = pdot(r, r)
+                with jax.named_scope("cg.pc"):
+                    zn = M3(r)
+                with jax.named_scope("cg.dot"):
+                    rz_new = pdot(r, zn)
                 zdir = zn
                 rn_new = jnp.sqrt(rr)
             else:
-                rr = pdot(r, r)
+                with jax.named_scope("cg.dot"):
+                    rr = pdot(r, r)
                 rz_new = rr * inv_diag
                 zdir = st_(r * inv_diag)
                 rn_new = jnp.sqrt(rr)
         else:
-            z = jnp.where(cm, M(r), st["z"])
+            with jax.named_scope("cg.pc"):
+                z = jnp.where(cm, M(r), st["z"])
             zdir = z
-            if g is not None:
-                rz_new, rn2, badM = g.p2(r, z)      # stacked phase 2
-                rn_new = jnp.sqrt(jnp.maximum(jnp.real(rn2), 0.0))
-            elif pduo is not None:
-                rz_new, rr = pduo(r, z)             # fused (rz, rr) pair
-                rn_new = jnp.sqrt(jnp.maximum(jnp.real(rr), 0.0))
-            else:
-                rz_new = pdot(r, z)                 # reduction phase 2
-                rn_new = None                       # phase 3 / natural below
+            with jax.named_scope("cg.dot"):
+                if g is not None:
+                    rz_new, rn2, badM = g.p2(r, z)  # stacked phase 2
+                    rn_new = jnp.sqrt(jnp.maximum(jnp.real(rn2), 0.0))
+                elif pduo is not None:
+                    rz_new, rr = pduo(r, z)         # fused (rz, rr) pair
+                    rn_new = jnp.sqrt(jnp.maximum(jnp.real(rr), 0.0))
+                else:
+                    rz_new = pdot(r, z)             # reduction phase 2
+                    rn_new = None                   # phase 3 / natural
         if natural and g is None and not stencil:
             brk_new = brk_new | (cont & (jnp.real(rz_new) < 0))
-        beta = jnp.where(rz == 0, 0.0, rz_new / jnp.where(rz == 0, 1.0, rz))
-        p = jnp.where(cm, st_(zdir + bp.ex(beta) * p), p)
-        rz = jnp.where(cont, rz_new, rz)
+        with jax.named_scope("cg.update"):
+            beta = jnp.where(rz == 0, 0.0,
+                             rz_new / jnp.where(rz == 0, 1.0, rz))
+            p = jnp.where(cm, st_(zdir + bp.ex(beta) * p), p)
+            rz = jnp.where(cont, rz_new, rz)
         if rn_new is None:
-            rn_new = _nat(rz_new) if natural else pnorm(r)
+            with jax.named_scope("cg.dot"):
+                rn_new = _nat(rz_new) if natural else pnorm(r)
         rn = jnp.where(cont, rn_new, st["rn"])
         it = it + cont.astype(jnp.int32)
 

@@ -2543,12 +2543,13 @@ def build_ksp_program(comm: DeviceComm, ksp_type: str, pc, operator,
         # epilogue: TRUE residual of the returned iterate against the RAW
         # rhs (matching the host-side oracle at reference test.py:148-149),
         # fused into the solve program — see the true_res docstring note;
-        # the norms accumulate in the reduce channel (_up)
-        r = _up(b - spmv_local(op_arrays, x))
-        bu = _up(b)
-        trn = jnp.sqrt(jnp.real(lax.psum(jnp.vdot(r, r), axis)))
-        bn = jnp.sqrt(jnp.real(lax.psum(jnp.vdot(bu, bu), axis)))
-        return trn, bn
+        # the norms accumulate in the reduce channel (_up); named
+        # ``true_residual`` in the HLO's op_name metadata
+        with jax.named_scope("true_residual"):
+            r = _up(b - spmv_local(op_arrays, x))
+            bu = _up(b)
+            return (jnp.sqrt(jnp.real(lax.psum(jnp.vdot(r, r), axis))),
+                    jnp.sqrt(jnp.real(lax.psum(jnp.vdot(bu, bu), axis))))
 
     if nullspace_dim:
         def local_fn(op_arrays, pc_arrays, ns_q, b, x0, rtol, atol, dtol,
@@ -2831,12 +2832,13 @@ def build_ksp_program_many(comm: DeviceComm, ksp_type: str, pc, operator,
         # batched true-residual epilogue (raw spmv + plain psum — the
         # verifier channel, exactly like the single-RHS _true_res_tail;
         # both per-column norm rows ride ONE stacked psum)
-        R = _up(B - spmv_many(op_arrays, X))
-        Bu = _up(B)
-        s = lax.psum(jnp.stack([jnp.real(jnp.sum(jnp.conj(R) * R, axis=0)),
-                                jnp.real(jnp.sum(jnp.conj(Bu) * Bu,
-                                                 axis=0))]), axis)
-        return jnp.sqrt(s[0]), jnp.sqrt(s[1])
+        with jax.named_scope("true_residual"):
+            R = _up(B - spmv_many(op_arrays, X))
+            Bu = _up(B)
+            s = lax.psum(jnp.stack(
+                [jnp.real(jnp.sum(jnp.conj(R) * R, axis=0)),
+                 jnp.real(jnp.sum(jnp.conj(Bu) * Bu, axis=0))]), axis)
+            return jnp.sqrt(s[0]), jnp.sqrt(s[1])
 
     def body(op_arrays, pc_arrays, B, X0, rtol, atol, dtol, maxit,
              guard_args=None):

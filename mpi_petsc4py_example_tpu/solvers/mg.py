@@ -37,6 +37,7 @@ device-count-independent (tests/test_mg_slab.py asserts this).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -53,6 +54,14 @@ _RSCALE = 4.0 ** (1.0 / 3.0) / 2.0
 # sweep (~3.3 HBM passes) instead of a 21-pass jnp stencil apply plus an
 # XLA update chain. The jnp body (single shared definition,
 # models/stencil.py) covers everything else — coarse levels, f64, CPU.
+# Each Pallas call is named by its level (``level`` below: 0 the finest),
+# so the device trace splits the cycle's kernel time by level.
+
+def _level_name(kernel, level):
+    """``<kernel>_l<level>``, the Pallas kernel's name at an MG level; None
+    (the kernel's own name) where no level is given."""
+    return None if level is None else f"{kernel.__name__}_l{level}"
+
 
 def _stencil7(u, halo_lo, halo_hi, platform=None):
     """7-point Dirichlet Laplacian on a z-slab with explicit z-halo planes
@@ -71,25 +80,28 @@ def _stencil7(u, halo_lo, halo_hi, platform=None):
     return StencilPoisson3D._stencil7_jnp(u, halo_lo, halo_hi)
 
 
-def _sweep(u, f, halo_lo, halo_hi, omega: float = _OMEGA, platform=None):
+def _sweep(u, f, halo_lo, halo_hi, omega: float = _OMEGA, platform=None,
+           level=None):
     """One damped-Jacobi sweep ``u + (ω/6)(f - A u)`` — fused Pallas pass
     where supported."""
     from ..ops.pallas_stencil import pallas_supported, stencil3d_smooth_pallas
     lz, ny, nx = u.shape
     if pallas_supported(ny, nx, u.dtype, platform):
-        return stencil3d_smooth_pallas(u, f, halo_lo[None], halo_hi[None],
-                                       lz, ny, nx, omega / 6.0)
+        return stencil3d_smooth_pallas(
+            u, f, halo_lo[None], halo_hi[None], lz, ny, nx, omega / 6.0,
+            name=_level_name(stencil3d_smooth_pallas, level))
     return u + (omega / 6.0) * (f - _stencil7(u, halo_lo, halo_hi, platform))
 
 
-def _residual(u, f, halo_lo, halo_hi, platform=None):
+def _residual(u, f, halo_lo, halo_hi, platform=None, level=None):
     """Residual ``f - A u`` — fused Pallas pass where supported."""
     from ..ops.pallas_stencil import (pallas_supported,
                                       stencil3d_residual_pallas)
     lz, ny, nx = u.shape
     if pallas_supported(ny, nx, u.dtype, platform):
-        return stencil3d_residual_pallas(u, f, halo_lo[None], halo_hi[None],
-                                         lz, ny, nx)
+        return stencil3d_residual_pallas(
+            u, f, halo_lo[None], halo_hi[None], lz, ny, nx,
+            name=_level_name(stencil3d_residual_pallas, level))
     return f - _stencil7(u, halo_lo, halo_hi, platform)
 
 
@@ -141,7 +153,8 @@ def cheby_omegas(degree: int, b: float = 2.0, a_frac: float = 0.25):
     return tuple(1.0 / r for r in roots)
 
 
-def _smooth(u, f, iters: int, exchange, omega=_OMEGA, platform=None):
+def _smooth(u, f, iters: int, exchange, omega=_OMEGA, platform=None,
+            level=None):
     """Damped-Jacobi sweeps for the unit 7-point stencil; ``omega`` may be
     a scalar (``iters`` equal sweeps, fori_loop) or a tuple of per-sweep
     factors (a Chebyshev-root schedule, unrolled — see cheby_omegas).
@@ -158,24 +171,27 @@ def _smooth(u, f, iters: int, exchange, omega=_OMEGA, platform=None):
                 try:
                     return stencil3d_smooth_pair_pallas(
                         u, f, lz, ny, nx, float(omega[0]) / 6.0,
-                        float(omega[1]) / 6.0)
+                        float(omega[1]) / 6.0,
+                        name=_level_name(stencil3d_smooth_pair_pallas,
+                                         level))
                 except ValueError:
                     pass    # no feasible >=2 z-chunk: two separate sweeps
         for w in omega:
             lo, hi = exchange(u)
-            u = _sweep(u, f, lo, hi, w, platform)
+            u = _sweep(u, f, lo, hi, w, platform, level)
         return u
     if iters <= 0:
         return u
 
     def body(_, u):
         lo, hi = exchange(u)
-        return _sweep(u, f, lo, hi, omega, platform)
+        return _sweep(u, f, lo, hi, omega, platform, level)
 
     return lax.fori_loop(0, iters, body, u)
 
 
-def _smooth0(f, iters: int, exchange, omega=_OMEGA, platform=None):
+def _smooth0(f, iters: int, exchange, omega=_OMEGA, platform=None,
+             level=None):
     """Sweeps from a ZERO initial guess: the first sweep is the closed form
     ``u = (ω/6) f`` — no stencil apply, no halo exchange. A scalar ω keeps
     the remaining sweeps in a fori_loop (the 20-sweep coarse solve must
@@ -192,12 +208,14 @@ def _smooth0(f, iters: int, exchange, omega=_OMEGA, platform=None):
             lz, ny, nx = f.shape
             if pallas_supported(ny, nx, f.dtype, platform):
                 return stencil3d_smooth0_pair_pallas(
-                    f, lz, ny, nx, ws[0] / 6.0, ws[1] / 6.0)
-        return _smooth((ws[0] / 6.0) * f, f, 0, exchange, ws[1:], platform)
+                    f, lz, ny, nx, ws[0] / 6.0, ws[1] / 6.0,
+                    name=_level_name(stencil3d_smooth0_pair_pallas, level))
+        return _smooth((ws[0] / 6.0) * f, f, 0, exchange, ws[1:], platform,
+                       level)
     if iters <= 0:
         return jnp.zeros_like(f)
     return _smooth((omega / 6.0) * f, f, iters - 1, exchange, omega,
-                   platform)
+                   platform, level)
 
 
 def _r1d(f, ax: int, lo=None, hi=None):
@@ -344,7 +362,7 @@ def _restrict(r, lo=None, hi=None, platform=None):
     return _r1d(_r1d(_r1d(r, 0, lo, hi), 1), 2)
 
 
-def _residual_restrict_fused(u, f, platform=None):
+def _residual_restrict_fused(u, f, platform=None, level=None):
     """Fine residual + full restriction fused INTO the residual kernel.
 
     Round 6: where the level shape allows it
@@ -374,15 +392,18 @@ def _residual_restrict_fused(u, f, platform=None):
             and fullrestrict_supported(ny, nx, u.dtype, platform)):
         dt = u.dtype
         return stencil3d_residual_restrict_pallas(
-            u, f, _tmat(ny, dt).T, _tmat(nx, dt), lz, ny, nx, _RSCALE)
+            u, f, _tmat(ny, dt).T, _tmat(nx, dt), lz, ny, nx, _RSCALE,
+            name=_level_name(stencil3d_residual_restrict_pallas, level))
     if (lz % 2 == 0 and pallas_supported(ny, nx, u.dtype, platform)
             and _mm_ok(u.dtype, platform)):
-        rz = stencil3d_residual_zrestrict_pallas(u, f, lz, ny, nx, _RSCALE)
+        rz = stencil3d_residual_zrestrict_pallas(
+            u, f, lz, ny, nx, _RSCALE,
+            name=_level_name(stencil3d_residual_zrestrict_pallas, level))
         dt = rz.dtype
         out = _hp("cyx,yd->cdx", rz, _tmat(ny, dt))
         return _hp("cdx,xe->cde", out, _tmat(nx, dt))
     lo, hi = _no_exchange(u)
-    r = _residual(u, f, lo, hi, platform)
+    r = _residual(u, f, lo, hi, platform, level)
     return _restrict(r, platform=platform)
 
 
@@ -423,6 +444,13 @@ def make_vcycle3d(nz: int, ny: int, nx: int, pre: int = 2, post: int = 2,
     sweeps with the Chebyshev-root ω schedule (:func:`cheby_omegas` —
     same per-sweep cost as Jacobi, better smoothing: 14 → 12 CG its at
     128³); ``'jacobi'`` keeps the fixed ω = 2/3 pair.
+
+    Each level's work sits in a ``jax.named_scope`` ``mg_l<li>`` (0 the
+    finest), with ``smooth_pre``, ``residual_restrict``, ``prolong``,
+    ``smooth_post`` and, on the coarsest, ``coarse`` inside; the scopes
+    reach the HLO's ``op_name`` metadata, and the level's Pallas kernels
+    carry ``_l<li>`` in their names. The coarser levels' cycle runs
+    between a level's restriction and its prolongation, outside its scope.
     """
     levels = mg_levels(nz, ny, nx)
     if smoother == "chebyshev":
@@ -433,15 +461,25 @@ def make_vcycle3d(nz: int, ny: int, nx: int, pre: int = 2, post: int = 2,
         raise ValueError(f"unknown MG smoother {smoother!r}; "
                          "available: 'chebyshev', 'jacobi'")
 
+    def scope(li, phase):
+        return jax.named_scope(f"mg_l{li}/{phase}")
+
     def local_cycle(f, li: int):
         if li == len(levels) - 1:
-            return _smooth0(f, coarse_iters, _no_exchange,
-                            platform=platform)
-        u = _smooth0(f, pre, _no_exchange, omega=pre_w, platform=platform)
-        e_c = local_cycle(_residual_restrict_fused(u, f, platform), li + 1)
-        u = u + _prolong(e_c, platform=platform)
-        return _smooth(u, f, post, _no_exchange, omega=post_w,
-                       platform=platform)
+            with scope(li, "coarse"):
+                return _smooth0(f, coarse_iters, _no_exchange,
+                                platform=platform, level=li)
+        with scope(li, "smooth_pre"):
+            u = _smooth0(f, pre, _no_exchange, omega=pre_w,
+                         platform=platform, level=li)
+        with scope(li, "residual_restrict"):
+            f_c = _residual_restrict_fused(u, f, platform, li)
+        e_c = local_cycle(f_c, li + 1)
+        with scope(li, "prolong"):
+            u = u + _prolong(e_c, platform=platform)
+        with scope(li, "smooth_post"):
+            return _smooth(u, f, post, _no_exchange, omega=post_w,
+                           platform=platform, level=li)
 
     if ndev == 1:
         return lambda f: local_cycle(f, 0)
@@ -468,15 +506,21 @@ def make_vcycle3d(nz: int, ny: int, nx: int, pre: int = 2, post: int = 2,
             e_full = local_cycle(f_full, li)
             i = lax.axis_index(axis)
             return lax.dynamic_slice_in_dim(e_full, i * lzi, lzi, axis=0)
-        u = _smooth0(f, pre, exchange, omega=pre_w, platform=platform)
-        lo, hi = exchange(u)
-        r = _residual(u, f, lo, hi, platform)
-        rlo, rhi = exchange(r)
-        e_c = slab_cycle(_restrict(r, rlo, rhi, platform), li + 1)
-        elo, ehi = exchange(e_c)
-        u = u + _prolong(e_c, elo, ehi, platform)
-        return _smooth(u, f, post, exchange, omega=post_w,
-                       platform=platform)
+        with scope(li, "smooth_pre"):
+            u = _smooth0(f, pre, exchange, omega=pre_w, platform=platform,
+                         level=li)
+        with scope(li, "residual_restrict"):
+            lo, hi = exchange(u)
+            r = _residual(u, f, lo, hi, platform, li)
+            rlo, rhi = exchange(r)
+            f_c = _restrict(r, rlo, rhi, platform)
+        e_c = slab_cycle(f_c, li + 1)
+        with scope(li, "prolong"):
+            elo, ehi = exchange(e_c)
+            u = u + _prolong(e_c, elo, ehi, platform)
+        with scope(li, "smooth_post"):
+            return _smooth(u, f, post, exchange, omega=post_w,
+                           platform=platform, level=li)
 
     return lambda f: slab_cycle(f, 0)
 

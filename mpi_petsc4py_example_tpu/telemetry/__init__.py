@@ -16,7 +16,10 @@ The observability layer PETSc deployments get from ``-log_view`` /
 * **flight recorder** (:mod:`.flight`) — a bounded ring of recent span
   trees + fault/recovery events, dumped automatically on unrecovered
   errors and on demand;
-* **trace export** (:mod:`.export`) — Chrome/Perfetto trace-event JSON.
+* **trace export** (:mod:`.export`) — Chrome/Perfetto trace-event JSON;
+* **compile spans** (:mod:`.compile_events`) — every trace, lower and
+  compile-or-cache-load JAX does while spans are armed, as ``compile.*``
+  spans under the span that caused it.
 
 Every name is registered in :mod:`.names` (``NAMES``) — validated at
 runtime and by tpslint TPS014.
@@ -26,7 +29,9 @@ cost class as the globals it replaced). SPANS + flight ring + trace are
 armed by :func:`enable` / the ``-telemetry`` flag; disabled they are a
 shared no-op context manager — no allocation, no clock read, no device
 work, zero extra XLA programs (the cfg12 bench gates the armed overhead
-at <2% wall).
+at <2% wall). :func:`enable` is the one call that touches jax: it
+registers the compile listener with ``jax.monitoring``, once per
+process; disabled, the listener returns at once.
 
 Runtime flags (utils/options): ``-telemetry`` (arm spans+flight),
 ``-telemetry_flight_len N`` (ring length), ``-telemetry_dump <path>``

@@ -57,8 +57,28 @@ class FlightRecorder:
                                               maxlen=max(1, int(n)))
 
     # ---- feeding ------------------------------------------------------------
-    def record_span(self, tree: dict):
+    def record_span(self, tree: dict, group: dict | None = None):
+        """Append a root span tree. With ``group`` (an empty span tree),
+        the tree goes in as a child of a group root instead: of the
+        newest entry when that is a group of the same name and thread,
+        else of ``group`` as a new entry. A burst of small roots (the
+        one-op programs JAX compiles outside any solve) then takes one
+        ring slot, and solve trees and fault events stay in the ring."""
         with self._lock:
+            if group is not None:
+                last = self._entries[-1] if self._entries else None
+                if (last is not None and last["type"] == "span"
+                        and last["span"]["name"] == group["name"]
+                        and last["span"]["thread"] == group["thread"]):
+                    self._entries.pop()
+                    group = last["span"]
+                else:
+                    group = dict(group, wall=tree["wall"], t0=tree["t0"],
+                                 t1=tree["t1"])
+                # a new dict: a tree handed out by spans() never changes
+                tree = dict(group, t0=min(group["t0"], tree["t0"]),
+                            t1=max(group["t1"], tree["t1"]),
+                            children=[*group["children"], tree])
             self._entries.append({"type": "span", "wall": time.time(),
                                   "span": tree})
 

@@ -37,6 +37,17 @@ NAMES = {
     "ksp.autoselect": ("span", "-ksp_reduction_auto: measured-latency "
                                "reduction-plan selection at KSP.setUp "
                                "(solvers/autoselect.py)"),
+    # ---- spans: program build (telemetry/compile_events.py) ----
+    "compile.trace": ("span", "JAX traced a function to a jaxpr (attr "
+                              "fun_name)"),
+    "compile.lower": ("span", "JAX lowered a jaxpr to an MLIR module "
+                              "(attr fun_name)"),
+    "compile.backend": ("span", "XLA compile or persistent-cache load of "
+                                "a lowered module (attrs fun_name, "
+                                "cache_hit)"),
+    "compile.group": ("span", "compile.* spans recorded with no span "
+                              "open, one after another: one flight-ring "
+                              "entry for the burst (children)"),
     # ---- spans: PC / EPS / refinement ----
     "pc.setup": ("span", "preconditioner factor build/placement (covers "
                          "the MG/GAMG hierarchy build — the MG entry)"),

@@ -19,11 +19,14 @@ finished explicitly and LINKED by attribute (``batch_span``), the
 Chrome-trace flow-event model without the event plumbing.
 
 The disabled path is free by construction: :func:`span` returns a shared
-no-op context manager — no allocation, no clock read, no ring append —
-and no telemetry code ever touches jax (zero extra XLA programs or
-device dispatches either way; ``tests/test_telemetry.py`` pins it with
-the live-arrays idiom). Timestamps are dual: ``wall`` (epoch seconds,
-for humans and cross-process alignment) and ``t0``/``t1``
+no-op context manager — no allocation, no clock read, no ring append.
+Telemetry code touches jax in one place only: :func:`enable` registers
+the compile listener (:mod:`.compile_events`), which records what JAX
+traces, lowers and compiles as ``compile.*`` spans. It adds no XLA
+program or device dispatch either way (``tests/test_telemetry.py`` pins
+it with the live-arrays idiom), and a process that never calls
+:func:`enable` registers nothing with jax. Timestamps are dual: ``wall``
+(epoch seconds, for humans and cross-process alignment) and ``t0``/``t1``
 (``perf_counter`` — monotonic, what durations and trace ``ts`` use).
 """
 
@@ -52,11 +55,14 @@ def enabled() -> bool:
 
 
 def enable(flight_len: int | None = None):
-    """Arm spans + flight recorder (+ optionally resize the ring)."""
+    """Arm spans + flight recorder (+ optionally resize the ring), and
+    register the compile listener with jax (once per process)."""
     global _ENABLED
     if flight_len is not None:
         from .flight import recorder
         recorder.set_maxlen(int(flight_len))
+    from .compile_events import listen
+    listen()
     _ENABLED = True
 
 
@@ -120,11 +126,14 @@ class Span:
                 st.pop()
             elif self in st:          # unbalanced exit: drop through to it
                 del st[st.index(self):]
+        self._attach()
+        return self
+
+    def _attach(self):
         if self.parent is not None:
             self.parent.children.append(self)
         else:
             _finish_root(self)
-        return self
 
     def to_dict(self) -> dict:
         return {"name": self.name, "span_id": self.span_id,
@@ -186,6 +195,28 @@ def start_span(name: str, **attrs):
     return Span(name, parent=None, attrs=attrs)
 
 
+def completed_span(name: str, t0: float, t1: float, group: Span,
+                   **attrs):
+    """Record a span that has already run, from ``t0`` to ``t1`` on the
+    ``perf_counter`` clock: a child of this thread's active span, or,
+    when none is open, of a root named as ``group`` (a detached span from
+    :func:`start_span`, never ended), which the roots of the same group
+    recorded right before it in the flight ring share (``FlightRecorder.
+    record_span``). For work timed by someone else (the compile
+    listener); no-op singleton when disabled."""
+    if not _ENABLED:
+        return NOOP
+    parent = _tls.stack[-1] if _tls.stack else None
+    sp = Span(name, parent=parent, attrs=attrs)
+    sp.wall -= sp.t0 - t0
+    sp.t0, sp.t1 = t0, t1
+    if parent is None:
+        _finish_root(sp, group=group)
+    else:
+        sp._attach()
+    return sp
+
+
 def current_span():
     """The active span on this thread (None when none / disabled)."""
     st = _tls.stack
@@ -210,7 +241,7 @@ def record_program_dispatch(kind: str, count: int = 1):
         root.attrs["dispatches"] = root.attrs.get("dispatches", 0) + count
 
 
-def _finish_root(sp: Span):
+def _finish_root(sp: Span, group: Span | None = None):
     if not _ENABLED:
         # a span opened while armed may finish after disable() (e.g. a
         # detached serving.request resolved later on the dispatcher
@@ -222,7 +253,8 @@ def _finish_root(sp: Span):
     # function-level import breaks the cycle at module-load time
     from .flight import recorder
     from .metrics import registry
-    recorder.record_span(sp.to_dict())
+    recorder.record_span(sp.to_dict(),
+                         group=group.to_dict() if group else None)
     registry.sample()
 
 

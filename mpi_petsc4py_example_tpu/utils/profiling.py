@@ -8,10 +8,9 @@ through the options DB [external]; equivalents here:
   through the compiled loop (no host callbacks — works on every runtime)
   and replayed to the user callbacks after the solve;
 * a solve-event log — every KSP/EPS solve records (solver, n, iterations,
-  wall, reason); ``log_view()`` prints the PETSc-``-log_view``-style summary,
-  automatically at exit when ``-log_view`` is set;
-* device tracing — :func:`trace` wraps ``jax.profiler.trace`` so a solve can
-  be captured for TensorBoard/XProf (``-tpu_profile <dir>``).
+  wall, reason), the newest ``metrics.RESERVOIR_LEN`` kept; ``log_view()``
+  prints the PETSc-``-log_view``-style summary, automatically at exit when
+  ``-log_view`` is set.
 
 Since the telemetry layer landed, this module is a COMPATIBILITY VIEW:
 every ``record_*`` function is a thin shim writing into the typed
@@ -27,9 +26,8 @@ lives in the registry.
 from __future__ import annotations
 
 import atexit
-import contextlib
+import collections
 import sys
-import time
 from dataclasses import dataclass
 
 from .options import global_options
@@ -49,7 +47,9 @@ class SolveEvent:
     reason: int
 
 
-_EVENTS: list[SolveEvent] = []
+# bounded like the histogram reservoirs: a serving process solves forever
+_EVENTS: collections.deque[SolveEvent] = collections.deque(
+    maxlen=_metrics.RESERVOIR_LEN)
 _atexit_armed = False
 
 
@@ -508,32 +508,3 @@ def program_count() -> int:
         pass
     return n
 
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """Capture a device trace of the enclosed block (XProf/TensorBoard)."""
-    import jax
-
-    with jax.profiler.trace(log_dir):
-        yield
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named region in device traces."""
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield
-
-
-class Timer:
-    """Simple wall-clock timer used by the bench harness."""
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.elapsed = time.perf_counter() - self.t0
-        return False

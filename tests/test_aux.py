@@ -227,6 +227,21 @@ class TestLogView:
         assert "KSPSolve(cg+none)" in out
         assert "solve(s), total wall" in out
 
+    def test_event_log_is_bounded(self):
+        """A process that solves forever keeps the newest RESERVOIR_LEN
+        solve events, not all of them."""
+        from mpi_petsc4py_example_tpu.telemetry.metrics import RESERVOIR_LEN
+        profiling.clear_events()
+        try:
+            for i in range(RESERVOIR_LEN + 3):
+                profiling.record_event("KSPSolve(cg+none)", 4, i, 1e-3, 2)
+            evs = profiling.events()
+            assert len(evs) == RESERVOIR_LEN
+            assert evs[0].iterations == 3
+            assert evs[-1].iterations == RESERVOIR_LEN + 2
+        finally:
+            profiling.clear_events()
+
     def test_convergence_history(self, comm8):
         """KSPSetResidualHistory analog: per-iteration residual norms."""
         A = poisson2d_csr(8)

@@ -129,3 +129,23 @@ def test_batched_fused_dot_k8(one_chip):
     u, plane = _slab(128, 256, k=8)
     _compiled(stencil3d_dot_many_pallas,
               [u, plane, plane, 128, 256, 256, 8], one_chip)
+
+
+def test_mg_vcycle_kernels_named_by_level(one_chip):
+    """The single-device V-cycle names each Pallas kernel by its MG level
+    (solvers/mg.py): the compiled program's custom calls, which the device
+    trace's op names come from, read ``<kernel>_l<level>``."""
+    import jax
+
+    from mpi_petsc4py_example_tpu.solvers.mg import make_vcycle3d
+    shape = (32, 256, 512)      # Pallas at l0-l2, full restriction at l0-l1
+    cycle = jax.jit(make_vcycle3d(*shape, platform="tpu"))
+    text = _compiled(cycle, [(shape, F32)], one_chip).as_text()
+    for name in ("stencil3d_smooth0_pair_pallas_l0",
+                 "stencil3d_residual_restrict_pallas_l0",
+                 "stencil3d_smooth_pair_pallas_l0",
+                 "stencil3d_residual_restrict_pallas_l1",
+                 "stencil3d_residual_zrestrict_pallas_l2",
+                 "stencil3d_smooth_pair_pallas_l2"):
+        assert f"%{name}." in text, name
+    assert "%stencil3d_smooth_pair_pallas." not in text

@@ -15,12 +15,22 @@ Pins the layer's contracts:
 * trace export: Chrome/Perfetto trace-event structural validity;
 * the disabled path: ZERO extra XLA programs and zero extra live device
   buffers (the test_donation live-arrays idiom) — and the armed path
-  adds no programs either (telemetry is pure host work).
+  adds no programs either (telemetry is pure host work);
+* compile spans: what JAX traces, lowers and compiles while armed
+  becomes ``compile.*`` children of the open span (of one shared
+  ``compile.group`` root, one ring slot, when none is open), nothing
+  while disarmed, and no listener is registered in a process that never
+  arms telemetry.
 """
 
 import json
+import os
+import subprocess
+import sys
+import textwrap
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -29,7 +39,8 @@ from mpi_petsc4py_example_tpu import telemetry
 from mpi_petsc4py_example_tpu.models import poisson2d_csr
 from mpi_petsc4py_example_tpu.resilience import faults as _faults
 from mpi_petsc4py_example_tpu.solvers.krylov import donation_supported
-from mpi_petsc4py_example_tpu.telemetry.flight import DEFAULT_FLIGHT_LEN
+from mpi_petsc4py_example_tpu.telemetry.flight import (DEFAULT_FLIGHT_LEN,
+                                                      record_fault)
 from mpi_petsc4py_example_tpu.utils import profiling
 
 RTOL = 1e-8
@@ -444,6 +455,154 @@ class TestDisabledPathFree:
             res = ksp.solve(b, x)
         assert res.converged
         assert len(jax.live_arrays()) == n0
+
+
+def _fresh_jit():
+    """A jit no earlier call has compiled (a new closure each time)."""
+    k = np.random.default_rng().integers(1 << 30)
+    return jax.jit(lambda v: v * 2.0 + float(k))
+
+
+def _run_py(code: str) -> str:
+    """Run ``code`` in a fresh CPU process; its last stdout line."""
+    p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                       capture_output=True, text=True, timeout=300,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert p.returncode == 0, p.stderr[-3000:]
+    return p.stdout.strip().splitlines()[-1]
+
+
+class TestCompileSpans:
+    COMPILE = ("compile.trace", "compile.lower", "compile.backend")
+
+    def test_fresh_jit_gets_compile_children(self):
+        f, x = _fresh_jit(), jnp.ones(4)
+        x.block_until_ready()
+        telemetry.enable()
+        with telemetry.span("ksp.dispatch"):
+            f(x).block_until_ready()
+        with telemetry.span("ksp.dispatch"):
+            f(x).block_until_ready()          # cached: nothing to build
+        first, second = telemetry.flight_recorder.spans()[-2:]
+        kids = first["children"]
+        assert {c["name"] for c in kids} == set(self.COMPILE)
+        for c in kids:
+            assert first["t0"] <= c["t0"] <= c["t1"] <= first["t1"], c
+            assert c["attrs"]["fun_name"]
+        backend = [c for c in kids if c["name"] == "compile.backend"]
+        assert all(c["attrs"]["cache_hit"] in (True, False)
+                   for c in backend)
+        assert second["children"] == []
+
+    def test_compile_without_open_span_joins_a_group(self):
+        f, x = _fresh_jit(), jnp.ones(4)
+        x.block_until_ready()
+        telemetry.enable()
+        f(x).block_until_ready()
+        _fresh_jit()(x).block_until_ready()
+        (group,) = telemetry.flight_recorder.spans()
+        assert group["name"] == "compile.group"
+        kids = group["children"]
+        names = [c["name"] for c in kids]     # traces nest: count >= 2
+        assert set(names) == set(self.COMPILE)
+        assert names.count("compile.backend") == 2
+        assert group["t0"] == min(c["t0"] for c in kids)
+        assert group["t1"] == max(c["t1"] for c in kids)
+
+    def test_eager_compiles_keep_solves_and_faults_in_the_ring(self):
+        """At the default ring length, 300 programs compiled outside any
+        span take one ring slot: the solve tree and the fault event
+        recorded before them stay."""
+        assert telemetry.flight_recorder.maxlen == DEFAULT_FLIGHT_LEN
+        x = jnp.ones(4)
+        x.block_until_ready()
+        telemetry.enable()
+        with telemetry.span("ksp.solve"):
+            pass
+        record_fault("ksp.solve", "unavailable")
+        for _ in range(300):
+            _fresh_jit()(x).block_until_ready()
+        with telemetry.span("ksp.solve"):
+            pass
+        kinds = [e["span"]["name"] if e["type"] == "span" else e["kind"]
+                 for e in telemetry.flight_recorder.entries()]
+        assert kinds == ["ksp.solve", "fault", "compile.group", "ksp.solve"]
+        group = telemetry.flight_recorder.spans()[1]
+        backend = [c for c in group["children"]
+                   if c["name"] == "compile.backend"]
+        assert len(backend) == 300
+
+    @pytest.mark.parametrize("armed_before", [False, True],
+                             ids=["never_enabled", "after_disable"])
+    def test_disabled_records_nothing(self, armed_before):
+        if armed_before:
+            telemetry.enable()
+            telemetry.disable()
+        f, x = _fresh_jit(), jnp.ones(4)
+        f(x).block_until_ready()
+        assert telemetry.flight_recorder.entries() == []
+        assert telemetry.span("ksp.solve") is telemetry.NOOP
+
+    def test_listener_registered_only_by_enable(self):
+        """A process that never arms telemetry registers nothing with
+        jax.monitoring, solves included; enable() registers once."""
+        out = _run_py("""
+            import numpy as np
+            from jax._src import monitoring
+            import mpi_petsc4py_example_tpu as tps
+            from mpi_petsc4py_example_tpu import telemetry
+            from mpi_petsc4py_example_tpu.models import poisson2d_csr
+
+            def ours():
+                return [f for f in monitoring.get_event_listeners()
+                        + monitoring.get_event_duration_listeners()
+                        if f.__module__.startswith("mpi_petsc4py")]
+
+            A = poisson2d_csr(6)
+            comm = tps.DeviceComm()
+            M = tps.Mat.from_scipy(comm, A)
+            ksp = tps.KSP().create(comm)
+            ksp.set_operators(M)
+            ksp.set_type("cg")
+            x, b = M.get_vecs()
+            b.set_global(A @ np.ones(A.shape[0]))
+            ksp.solve(b, x)
+            before = len(ours())
+            telemetry.enable()
+            telemetry.disable()
+            telemetry.enable()
+            print(before, len(ours()))
+            """)
+        assert out == "0 2", out
+
+    def test_persistent_cache_load_is_a_hit(self, tmp_path):
+        """A program loaded back from the persistent compile cache records
+        its compile.backend span with cache_hit true; the first build
+        records false."""
+        out = _run_py(f"""
+            import jax
+            import jax.numpy as jnp
+            from mpi_petsc4py_example_tpu import telemetry
+            # after the package import, which sets its own cache options
+            jax.config.update("jax_compilation_cache_dir", {str(tmp_path)!r})
+            jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                              0)
+            x = jnp.arange(8.0)
+            x.block_until_ready()
+            telemetry.enable()
+
+            def hits():
+                with telemetry.span("ksp.dispatch"):
+                    jax.jit(lambda v: v * 3.0 - 1.0)(x).block_until_ready()
+                kids = telemetry.flight_recorder.spans()[-1]["children"]
+                return [c["attrs"]["cache_hit"] for c in kids
+                        if c["name"] == "compile.backend"]
+
+            first = hits()
+            jax.clear_caches()
+            print(first, hits())
+            """)
+        assert out == "[False] [True]", out
 
 
 class TestOptionsWiring:

@@ -4,8 +4,8 @@ Two registries back the observability layer, and both are enforced here
 (the TPS007/TPS012 pattern applied to telemetry):
 
 1. **Name registry** — every ``span("...")`` / ``start_span("...")`` /
-   ``registry.counter("...")`` / ``.gauge("...")`` / ``.histogram("...")``
-   call site must name an entry of ``telemetry/names.NAMES``: a typo'd
+   ``completed_span("...")`` / ``registry.counter("...")`` /
+   ``.gauge("...")`` / ``.histogram("...")`` call site must name an entry of ``telemetry/names.NAMES``: a typo'd
    span or metric name otherwise records into a parallel universe — the
    dashboards and traces built on the registered name silently show
    nothing. (The runtime ALSO validates, but only on the paths a test
@@ -38,7 +38,7 @@ from .base import Rule, register
 from .tps012_fault_registry import registered_fault_points
 
 #: call shapes that take a telemetry NAME as their first argument
-_SPAN_HOOKS = ("span", "start_span")
+_SPAN_HOOKS = ("span", "start_span", "completed_span")
 _METRIC_HOOKS = ("counter", "gauge", "histogram")
 #: receivers the repo binds the span API / metrics registry to
 _SPAN_RECEIVERS = ("telemetry", "_telemetry", "spans", "_spans")
