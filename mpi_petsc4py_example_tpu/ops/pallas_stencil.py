@@ -767,7 +767,10 @@ def _pick_chunk_zrestrict(lz: int, itemsize: int, ny: int, nx: int,
                           max_chunk: int | None):
     """Even z-chunk dividing ``lz`` for the fused residual+z-restrict
     pipeline: scratch is 2 u-banks (chunk+4 planes), 2 f-banks (chunk+2)
-    and 2 half-size out-banks (chunk/2) = 5·chunk + 12 planes."""
+    and 2 half-size out-banks (chunk/2) = 5·chunk + 12 planes. The fused
+    prolongation's scratch (~4.25·chunk + 3 planes) fits the same plan;
+    on v5e its time a call is the same at chunks 4 to 16 (512² and 256²
+    planes)."""
     plane = ny * nx * itemsize
     budget_planes = int(_vmem_plan(_tpu_device_kind())[1] // plane)
     chunk = max(2, min(lz, (budget_planes - 12) // 5))
@@ -1147,6 +1150,186 @@ def stencil3d_residual_restrict_pallas(u, f, wyt, wx, lz: int, ny: int,
         compiler_params=_vmem_limit_params(interpret),
         interpret=interpret,
     )(u, f, wyt, wx)
+
+
+def _prolong_add_kernel(u_ref, e_ref, wy_ref, wxt_ref, out_ref, chunk,
+                        nchunks):
+    """Fused ``u + P e``, manual-DMA z-chunk pipeline: the upward leg's
+    counterpart of :func:`_resid_restrict3_kernel`.
+
+    Per coarse plane ``e[i]`` the y/x prolongation is two MXU matmuls,
+    ``Q[i] = wy @ e[i] @ wxt`` (``wy`` the (ny, ny/2) and ``wxt`` the
+    (nx/2, nx) one-axis prolongation matrices); z is then the VPU pair
+    ``fine[2i] = 0.75·Q[i] + 0.25·Q[i-1]``,
+    ``fine[2i+1] = 0.75·Q[i] + 0.25·Q[i+1]``, added to u and written.
+    Fine chunk ``c`` (coarse planes ``i0 = c·chunk/2`` on) needs
+    ``Q[i0-1 .. i0+chunk/2]``: the two lowest are the previous chunk's
+    two highest, carried in VMEM, so each coarse plane is multiplied
+    once. The chunk's coarse bank holds ``e[i0+1 .. i0+chunk/2]``, whose
+    top plane lies beyond the domain on the last chunk: that DMA is
+    skipped and the plane masked to the zero ghost on the VALUE; chunk 0
+    takes ``e[0]`` in the prologue, with ``Q[-1] = 0``. Neither the
+    correction nor an intermediate touches HBM: read u and e, write u.
+    SINGLE-DEVICE slabs only (zero Dirichlet ghosts built in).
+    """
+    ny, nx = u_ref.shape[1], u_ref.shape[2]
+    nyc, nxc = e_ref.shape[1], e_ref.shape[2]
+    cc = chunk // 2
+    dt = out_ref.dtype
+
+    def process(usc, esc, osc, e0, car, sem_u, sem_e, sem_eh, sem_0,
+                sem_out):
+        one = jnp.int32(1)
+
+        def lax_rem(c):
+            return jax.lax.rem(c, jnp.int32(2))
+
+        def start_in(c, slot):
+            z0 = c * jnp.int32(chunk)
+            i0 = c * jnp.int32(cc)
+            pltpu.make_async_copy(u_ref.at[pl.ds(z0, chunk)], usc.at[slot],
+                                  sem_u.at[slot]).start()
+            if cc > 1:
+                pltpu.make_async_copy(
+                    e_ref.at[pl.ds(i0 + one, cc - 1)],
+                    esc.at[slot, pl.ds(0, cc - 1)], sem_e.at[slot]).start()
+
+            @pl.when(c < nchunks - 1)
+            def _():
+                pltpu.make_async_copy(
+                    e_ref.at[pl.ds(i0 + jnp.int32(cc), 1)],
+                    esc.at[slot, pl.ds(jnp.int32(cc - 1), 1)],
+                    sem_eh.at[slot]).start()
+
+        def wait_in(c, slot):
+            pltpu.make_async_copy(u_ref.at[pl.ds(0, chunk)], usc.at[slot],
+                                  sem_u.at[slot]).wait()
+            if cc > 1:
+                pltpu.make_async_copy(
+                    e_ref.at[pl.ds(0, cc - 1)],
+                    esc.at[slot, pl.ds(0, cc - 1)], sem_e.at[slot]).wait()
+
+            @pl.when(c < nchunks - 1)
+            def _():
+                pltpu.make_async_copy(
+                    e_ref.at[pl.ds(0, 1)],
+                    esc.at[slot, pl.ds(jnp.int32(cc - 1), 1)],
+                    sem_eh.at[slot]).wait()
+
+        def pyx(plane):
+            # fp32 contract precision, as the einsum path: Mosaic's default
+            # f32 dot is one bf16 pass (measured on v5e: P e off by 1.5e-3
+            # relative, and CG+MG at 512³ took 9 iterations, not 8)
+            hi = jax.lax.Precision.HIGHEST
+            t = jax.lax.dot(wy_ref[...], plane, precision=hi,
+                            preferred_element_type=dt)
+            return jax.lax.dot(t, wxt_ref[...], precision=hi,
+                               preferred_element_type=dt)
+
+        start_in(jnp.int32(0), jnp.int32(0))
+        first = pltpu.make_async_copy(e_ref.at[pl.ds(0, 1)], e0, sem_0)
+        first.start()
+        first.wait()
+        car[0] = jnp.zeros((ny, nx), dt)        # Q[-1], the zero ghost
+        car[1] = pyx(e0[0])                     # Q[0]
+        quarter = jnp.asarray(0.25, dt)
+        three = jnp.asarray(0.75, dt)
+
+        def body(c, carry):
+            slot = lax_rem(c)
+
+            @pl.when(c + 1 < nchunks)
+            def _():
+                start_in(c + 1, lax_rem(c + 1))
+
+            wait_in(c, slot)
+
+            @pl.when(c >= 2)
+            def _():
+                pltpu.make_async_copy(
+                    osc.at[slot], out_ref.at[pl.ds(0, chunk)],
+                    sem_out.at[slot]).wait()
+            qm, q0 = car[0], car[1]
+            for k in range(cc):         # coarse plane i0 + k
+                top = esc[slot, k]
+                if k == cc - 1:         # e[lzc] lies beyond the domain
+                    top = jnp.where(c == nchunks - 1, 0.0, top)
+                qp = pyx(top)
+                osc[slot, 2 * k] = usc[slot, 2 * k] + (three * q0
+                                                       + quarter * qm)
+                osc[slot, 2 * k + 1] = usc[slot, 2 * k + 1] + (
+                    three * q0 + quarter * qp)
+                qm, q0 = q0, qp
+            car[0] = qm
+            car[1] = q0
+            pltpu.make_async_copy(
+                osc.at[slot], out_ref.at[pl.ds(c * jnp.int32(chunk), chunk)],
+                sem_out.at[slot]).start()
+            return carry
+
+        jax.lax.fori_loop(jnp.int32(0), jnp.int32(nchunks), body,
+                          jnp.int32(0))
+        last = jnp.int32(nchunks - 1)
+
+        @pl.when(jnp.int32(nchunks) >= 2)
+        def _():
+            pltpu.make_async_copy(
+                osc.at[lax_rem(last + 1)], out_ref.at[pl.ds(0, chunk)],
+                sem_out.at[lax_rem(last + 1)]).wait()
+
+        pltpu.make_async_copy(
+            osc.at[lax_rem(last)], out_ref.at[pl.ds(0, chunk)],
+            sem_out.at[lax_rem(last)]).wait()
+
+    scratch = [
+        pltpu.VMEM((2, chunk, ny, nx), dt),
+        pltpu.VMEM((2, cc, nyc, nxc), dt),
+        pltpu.VMEM((2, chunk, ny, nx), dt),
+        pltpu.VMEM((1, nyc, nxc), dt),
+        pltpu.VMEM((2, ny, nx), dt),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SemaphoreType.DMA(()),
+        pltpu.SemaphoreType.DMA((2,)),
+    ]
+    pl.run_scoped(process, *scratch)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8),
+                   static_argnames=("name",))
+def stencil3d_prolong_add_pallas(u, e_c, wy, wxt, lz: int, ny: int, nx: int,
+                                 interpret: bool = False,
+                                 max_chunk: int | None = None,
+                                 *, name: str | None = None):
+    """Fused prolongation + correction for SINGLE-DEVICE slabs:
+    ``u + P e_c`` with solvers/mg's transfer weights and zero ghosts, for
+    the (lz/2, ny/2, nx/2) coarse correction ``e_c``, without the
+    prolonged correction or any intermediate touching HBM (see
+    :func:`_prolong_add_kernel`). ``wy``/``wxt`` are the y and transposed
+    x one-axis prolongation matrices (mg._tmat(ny, dt, 1.0) /
+    mg._tmat(nx, dt, 1.0).T)."""
+    if lz % 2 or ny % 2 or nx % 2:
+        raise ValueError(f"fused prolongation needs even dims, got "
+                         f"({lz}, {ny}, {nx})")
+    chunk, nchunks = _pick_chunk_zrestrict(lz, u.dtype.itemsize, ny, nx,
+                                           max_chunk)
+    kernel = functools.partial(_prolong_add_kernel, chunk=chunk,
+                               nchunks=nchunks)
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((lz, ny, nx), u.dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  # the two small transfer matrices ride the automatic
+                  # VMEM staging, as in the restriction kernel
+                  pl.BlockSpec(memory_space=pltpu.VMEM),
+                  pl.BlockSpec(memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        name=name or "stencil3d_prolong_add_pallas",
+        compiler_params=_vmem_limit_params(interpret),
+        interpret=interpret,
+    )(u, e_c, wy, wxt)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7),

@@ -271,11 +271,12 @@ def _p1d(c, ax: int, lo=None, hi=None):
 _TMAT_CACHE: dict = {}
 
 
-def _tmat(n: int, dtype):
+def _tmat(n: int, dtype, scale: float = _RSCALE):
     """(n, n/2) one-axis restriction matrix: column i carries the weights
-    _RSCALE·[1/4, 3/4, 3/4, 1/4] on rows [2i-1, 2i+2] (zero ghosts).
+    scale·[1/4, 3/4, 3/4, 1/4] on rows [2i-1, 2i+2] (zero ghosts).
     Its transpose is the one-axis prolongation (the R = (1/2)Pᵀ pair, per
-    axis). A 512-wide axis costs 512×256×4B = 512 KB as a constant."""
+    axis); ``scale=1`` gives P's own weights, exact in any float dtype.
+    A 512-wide axis costs 512×256×4B = 512 KB as a constant."""
     # cache HOST numpy, convert per call: caching a jnp array built inside
     # a trace would leak that trace's tracer into every later program
     Wn = _TMAT_CACHE.get(n)
@@ -287,9 +288,8 @@ def _tmat(n: int, dtype):
         Wn[2 * i + 1, i] = 0.75
         Wn[2 * i[1:] - 1, i[1:]] = 0.25
         Wn[2 * i[:-1] + 2, i[:-1]] = 0.25
-        Wn = _RSCALE * Wn
         _TMAT_CACHE[n] = Wn
-    return jnp.asarray(Wn, dtype)
+    return jnp.asarray(scale * Wn, dtype)
 
 
 def _mm_ok(dtype, platform=None) -> bool:
@@ -414,6 +414,28 @@ def _prolong(e, lo=None, hi=None, platform=None):
     return _p1d(_p1d(_p1d(e, 0, lo, hi), 1), 2)
 
 
+def _prolong_add(u, e_c, platform=None, level=None):
+    """Coarse-grid correction ``u + P e_c`` on a SINGLE-DEVICE slab.
+
+    Where the level's coarse planes stay (8, 128)-tileable in fp32
+    (the restriction kernel's gate) it is one streamed Pallas pass
+    (stencil3d_prolong_add_pallas: read u and e_c, write u, the y/x
+    transfer as in-VMEM MXU matmuls on coarse planes), where the einsum
+    chain runs its z and y stages on fine-sized arrays and writes both
+    intermediates to HBM. Elsewhere ``u + _prolong(e_c)``. The weights
+    are P's on both paths (pinned in tests/test_pallas.py)."""
+    from ..ops.pallas_stencil import (fullrestrict_supported,
+                                      stencil3d_prolong_add_pallas)
+    lz, ny, nx = u.shape
+    if (_mm_ok(u.dtype, platform)
+            and fullrestrict_supported(ny, nx, u.dtype, platform)):
+        dt = u.dtype
+        return stencil3d_prolong_add_pallas(
+            u, e_c, _tmat(ny, dt, 1.0), _tmat(nx, dt, 1.0).T, lz, ny, nx,
+            name=_level_name(stencil3d_prolong_add_pallas, level))
+    return u + _prolong(e_c, platform=platform)
+
+
 def mg_levels(nz: int, ny: int, nx: int, min_dim: int = 4):
     """Grid hierarchy: halve every dimension while all stay even and big."""
     levels = [(nz, ny, nx)]
@@ -476,7 +498,7 @@ def make_vcycle3d(nz: int, ny: int, nx: int, pre: int = 2, post: int = 2,
             f_c = _residual_restrict_fused(u, f, platform, li)
         e_c = local_cycle(f_c, li + 1)
         with scope(li, "prolong"):
-            u = u + _prolong(e_c, platform=platform)
+            u = _prolong_add(u, e_c, platform, li)
         with scope(li, "smooth_post"):
             return _smooth(u, f, post, _no_exchange, omega=post_w,
                            platform=platform, level=li)

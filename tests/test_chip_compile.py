@@ -114,6 +114,17 @@ def test_mg_residual_restrict(one_chip):
                _RSCALE], one_chip)
 
 
+def test_mg_prolong_add(one_chip):
+    """The fused prolongation + correction of the V-cycle's finest
+    single-device level (coarse planes 128², fine 256²)."""
+    from mpi_petsc4py_example_tpu.ops.pallas_stencil import (
+        stencil3d_prolong_add_pallas)
+    u, _ = _slab(256, 256)
+    _compiled(stencil3d_prolong_add_pallas,
+              [u, ((128, 128, 128), F32), ((256, 128), F32),
+               ((128, 256), F32), 256, 256, 256], one_chip)
+
+
 def test_batched_apply_k8(one_chip):
     from mpi_petsc4py_example_tpu.ops.pallas_stencil import (
         stencil3d_apply_many_pallas)
@@ -138,14 +149,18 @@ def test_mg_vcycle_kernels_named_by_level(one_chip):
     import jax
 
     from mpi_petsc4py_example_tpu.solvers.mg import make_vcycle3d
-    shape = (32, 256, 512)      # Pallas at l0-l2, full restriction at l0-l1
+    # Pallas at l0-l2; full restriction and fused prolongation at l0-l1
+    shape = (32, 256, 512)
     cycle = jax.jit(make_vcycle3d(*shape, platform="tpu"))
     text = _compiled(cycle, [(shape, F32)], one_chip).as_text()
     for name in ("stencil3d_smooth0_pair_pallas_l0",
                  "stencil3d_residual_restrict_pallas_l0",
+                 "stencil3d_prolong_add_pallas_l0",
                  "stencil3d_smooth_pair_pallas_l0",
                  "stencil3d_residual_restrict_pallas_l1",
+                 "stencil3d_prolong_add_pallas_l1",
                  "stencil3d_residual_zrestrict_pallas_l2",
                  "stencil3d_smooth_pair_pallas_l2"):
         assert f"%{name}." in text, name
     assert "%stencil3d_smooth_pair_pallas." not in text
+    assert "stencil3d_prolong_add_pallas_l2" not in text

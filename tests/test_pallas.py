@@ -286,6 +286,102 @@ def test_fused_residual_restrict3_rejects_odd_dims():
             4, 7, 128, mg._RSCALE, True, None)
 
 
+def _prolong_add_interpret(u, e, max_chunk=None):
+    import mpi_petsc4py_example_tpu.solvers.mg as mg
+    from mpi_petsc4py_example_tpu.ops.pallas_stencil import (
+        stencil3d_prolong_add_pallas)
+    lz, ny, nx = u.shape
+    dt = jnp.float32
+    return stencil3d_prolong_add_pallas(
+        jnp.asarray(u, dt), jnp.asarray(e, dt), mg._tmat(ny, dt, 1.0),
+        mg._tmat(nx, dt, 1.0).T, lz, ny, nx, True, max_chunk)
+
+
+@pytest.mark.parametrize("lz,ny,nx,max_chunk", [
+    (4, 8, 128, None),          # single chunk (both ghost planes in one)
+    (8, 8, 128, 2),             # one coarse plane a chunk, all carried
+    (12, 16, 128, 4),           # multi-chunk: Q crosses chunk boundaries
+    (6, 16, 256, 2),            # the production tileable-coarse shape class
+])
+def test_fused_prolong_add_parity(lz, ny, nx, max_chunk):
+    """stencil3d_prolong_add_pallas == u + mg._prolong_mm(e) with zero
+    Dirichlet ghosts — the V-cycle's upward leg in one streamed pass
+    (mg._prolong_add), its correction never in HBM."""
+    import mpi_petsc4py_example_tpu.solvers.mg as mg
+    rng = np.random.default_rng(800 + lz + nx)
+    u = rng.standard_normal((lz, ny, nx)).astype(np.float32)
+    e = rng.standard_normal((lz // 2, ny // 2, nx // 2)).astype(np.float32)
+    ref = u + np.asarray(mg._prolong_mm(jnp.asarray(e, jnp.float64),
+                                        None, None))
+    out = np.asarray(_prolong_add_interpret(u, e, max_chunk))
+    assert out.shape == (lz, ny, nx)
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+
+
+def test_fused_prolong_add_adjoint_of_restriction():
+    """<P e, r> == 2<e, R r> through the fused kernel (u = 0): P = 2·Rᵀ
+    keeps the V-cycle symmetric, so a valid CG preconditioner (the einsum
+    pair's pin is tests/test_mg_slab.py::test_transfer_adjointness)."""
+    import mpi_petsc4py_example_tpu.solvers.mg as mg
+    rng = np.random.default_rng(3)
+    lz, ny, nx = 8, 16, 256
+    r = rng.standard_normal((lz, ny, nx))
+    e = rng.standard_normal((lz // 2, ny // 2, nx // 2)).astype(np.float32)
+    pe = np.asarray(_prolong_add_interpret(np.zeros((lz, ny, nx)), e, 2))
+    lhs = float(np.vdot(pe.astype(np.float64), r))
+    rhs = 2.0 * float(np.vdot(e.astype(np.float64),
+                              np.asarray(mg._restrict(jnp.asarray(r)))))
+    assert abs(lhs - rhs) <= 1e-5 * max(abs(rhs), 1.0), (lhs, rhs)
+
+
+def test_fused_prolong_add_rejects_odd_dims():
+    from mpi_petsc4py_example_tpu.ops.pallas_stencil import (
+        stencil3d_prolong_add_pallas)
+    import mpi_petsc4py_example_tpu.solvers.mg as mg
+    u = jnp.zeros((4, 7, 128), jnp.float32)
+    e = jnp.zeros((2, 3, 64), jnp.float32)
+    with pytest.raises(ValueError, match="even dims"):
+        stencil3d_prolong_add_pallas(
+            u, e, mg._tmat(8, jnp.float32, 1.0),
+            mg._tmat(128, jnp.float32, 1.0).T, 4, 7, 128, True, None)
+
+
+@pytest.mark.parametrize("shape,dtype,platform,engaged", [
+    ((8, 16, 256), np.float32, "tpu", True),
+    ((8, 8, 128), np.float32, "tpu", False),    # coarse planes untileable
+    ((8, 16, 256), np.float64, "tpu", False),
+    ((8, 16, 256), jnp.bfloat16, "tpu", False),
+    ((8, 16, 256), np.float32, "cpu", False),
+])
+def test_prolong_add_gate(monkeypatch, shape, dtype, platform, engaged):
+    """mg._prolong_add takes the fused kernel only for fp32 TPU levels
+    with (8, 128)-tileable coarse planes; every other level keeps
+    ``u + _prolong(e)``, and both give the same correction."""
+    import mpi_petsc4py_example_tpu.ops.pallas_stencil as ps
+    import mpi_petsc4py_example_tpu.solvers.mg as mg
+    calls = []
+    real = ps.stencil3d_prolong_add_pallas
+
+    def kernel(u, e, wy, wxt, lz, ny, nx, *, name):
+        calls.append(name)
+        return real(u, e, wy, wxt, lz, ny, nx, True)
+
+    kernel.__name__ = real.__name__         # mg names it by level from this
+    monkeypatch.setattr(ps, "stencil3d_prolong_add_pallas", kernel)
+    rng = np.random.default_rng(9)
+    u = jnp.asarray(rng.standard_normal(shape), dtype)
+    e = jnp.asarray(rng.standard_normal(tuple(s // 2 for s in shape)),
+                    dtype)
+    out = mg._prolong_add(u, e, platform, level=1)
+    assert calls == (["stencil3d_prolong_add_pallas_l1"] if engaged else [])
+    ref = (np.asarray(u, np.float64)
+           + np.asarray(mg._prolong_mm(jnp.asarray(e, jnp.float64),
+                                       None, None)))
+    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5     # 3 bf16 roundings
+    np.testing.assert_allclose(np.asarray(out, np.float64), ref, rtol=tol,
+                               atol=tol)
+
+
 def test_fullrestrict_gate():
     """The 3-axis fusion additionally needs (8,128)-tileable COARSE
     planes; shapes that fail it still take the z-only fusion tier."""
