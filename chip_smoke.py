@@ -23,9 +23,12 @@ Phases (one chip):
   with ``-n 4``, in this process (the ranks are threads).
 - ``stencil``: StencilPoisson3D 256³ fp32, CG + jacobi and CG + mg to rtol
   1e-6; the Pallas kernels must be in the solve program.
-- ``assembled``: Mat.from_scipy fp64 — BCGS + bjacobi on convdiff2d(512)
-  with ``-pc_setup_device auto``, GMRES + jacobi (``-ksp_monitor``) on
-  poisson2d_csr(512).
+- ``assembled``: Mat.from_scipy fp64 — BCGS + bjacobi on convdiff2d(512),
+  once with its default ILU(0) blocks and once with 128 dense blocks
+  (``-pc_bjacobi_blocks 128 -pc_setup_device auto``, inverted on the device
+  or rejected by its quality gate), GMRES + jacobi (``-ksp_monitor``) on
+  poisson2d_csr(512), and the ILU(0) block apply on convdiff2d(2048)
+  against numpy.
 - ``serving``: a SolveServer on the 128³ stencil, 16 requests of mixed
   rtol, per-batch and persistent.
 - ``gates``: what the chip shows for the capability gates of
@@ -258,24 +261,119 @@ def phase_stencil(comm, nx: int = 256, rtol: float = 1e-6):
              warm_s=f"{warm:.3f}", path=pallas_path(comm, op, ksp, b, x))
 
 
+def ilu0_blocks_numpy(A, r: np.ndarray, m: int, bs: int) -> np.ndarray:
+    """``(LU)^-1 r`` for ILU(0) of the ``bs``-row diagonal blocks of the
+    five-point matrix ``A`` (lines of ``m`` points), in natural order row
+    by row and every block at once, in fp64 on the host: the pivots, then
+    the forward and backward substitutions, from A's diagonals alone."""
+    n = A.shape[0]
+    nb = n // bs
+
+    def diag(off):
+        out = np.zeros(n)
+        out[max(0, -off):n - max(0, off)] = A.diagonal(off)
+        return out.reshape(nb, bs)
+
+    s, w, a, e, nn = (diag(o) for o in (-m, -1, 0, 1, m))
+    d = np.empty((nb, bs))
+    for q in range(bs):
+        v = a[:, q].copy()
+        if q >= 1:
+            v -= w[:, q] * e[:, q - 1] / d[:, q - 1]
+        if q >= m:
+            v -= s[:, q] * nn[:, q - m] / d[:, q - m]
+        d[:, q] = v
+    r = r.reshape(nb, bs)
+    y = np.empty((nb, bs))
+    for q in range(bs):
+        v = r[:, q].copy()
+        if q >= 1:
+            v -= w[:, q] / d[:, q - 1] * y[:, q - 1]
+        if q >= m:
+            v -= s[:, q] / d[:, q - m] * y[:, q - m]
+        y[:, q] = v
+    z = np.empty((nb, bs))
+    for q in reversed(range(bs)):
+        v = y[:, q].copy()
+        if q + 1 < bs:
+            v -= e[:, q] * z[:, q + 1]
+        if q + m < bs:
+            v -= nn[:, q] * z[:, q + m]
+        z[:, q] = v / d[:, q]
+    return z.reshape(-1)
+
+
+def ilu_check(comm, nx: int = 2048, reps: int = 20):
+    """PC bjacobi's ILU(0) blocks on ``convdiff2d(nx)`` fp64: one seeded
+    vector through the device apply against :func:`ilu0_blocks_numpy`,
+    and the device apply's warm time."""
+    import jax
+
+    import mpi_petsc4py_example_tpu as tps
+    from mpi_petsc4py_example_tpu.models import convdiff2d
+    from mpi_petsc4py_example_tpu.solvers import bjilu
+
+    A = convdiff2d(nx)
+    M = tps.Mat.from_scipy(comm, A)
+    pc = tps.PC(comm)
+    pc.set_type("bjacobi")
+    pc.set_up(M)
+    check(pc.sub_solve == "ilu0", f"bjacobi took {pc.sub_solve!r} blocks")
+    info = pc.setup_breakdown
+    check(info["apply"] == "pallas",
+          f"the ILU(0) blocks apply by {info['apply']!r}, not the kernel")
+    bs = info["lines_per_block"] * nx
+    r = np.random.default_rng(SEED).standard_normal(A.shape[0])
+    apply = jax.jit(bjilu.apply)
+    rd = jax.device_put(r, comm.row_sharding)
+    z = np.asarray(apply(pc.device_arrays(), rd))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        zd = apply(pc.device_arrays(), rd)
+    zd.block_until_ready()
+    apply_ms = 1e3 * (time.perf_counter() - t0) / reps
+    zr = ilu0_blocks_numpy(A, r, nx, bs)
+    err = float(np.max(np.abs(z - zr)) / np.max(np.abs(zr)))
+    check(err <= 1e-12, f"ILU(0) apply differs from numpy by {err:.3e}")
+    emit("assembled", f"ILU(0) blocks convdiff2d({nx}) fp64 apply",
+         blocks=pc.sub_blocks, lines_per_block=info["lines_per_block"],
+         max_rel_diff=fmt(err), apply_ms=f"{apply_ms:.3f}",
+         setup=info)
+
+
 def phase_assembled(comm, nx: int = 512, rtol: float = 1e-6):
     from mpi_petsc4py_example_tpu.models import convdiff2d, poisson2d_csr
     from mpi_petsc4py_example_tpu.solvers import pc as pcmod
     from mpi_petsc4py_example_tpu.utils import native
 
-    g0 = pcmod.gate_fallbacks["block"]
     A = convdiff2d(nx)
     res, ksp, x, rel, wall = assembled_solve(
-        comm, A, "bcgs", "bjacobi", rtol,
-        options=["-pc_setup_device", "auto"])
+        comm, A, "bcgs", "bjacobi", rtol)
     pc = ksp.get_pc()
-    gate = pcmod.gate_fallbacks["block"] - g0
-    check(pc.setup_mode == "device" or gate > 0,
-          f"bjacobi setup ran on {pc.setup_mode!r} with no gate rejection")
+    check(pc.sub_solve == "ilu0",
+          f"bjacobi on convdiff2d({nx}) took {pc.sub_solve!r} blocks, "
+          "not ILU(0)")
     emit("assembled", f"BCGS+bjacobi convdiff2d({nx}) fp64 rtol {rtol:g}",
          iters=res.iterations, relres=fmt(rel), wall_s=f"{wall:.2f}",
-         pc_setup=pc.setup_mode, gate_fallbacks=gate,
+         sub_solve=pc.sub_solve, blocks=pc.sub_blocks,
+         apply=pc.setup_breakdown["apply"], pc_setup=pc.setup_mode,
          native=native.status())
+
+    g0 = pcmod.gate_fallbacks["block"]
+    res, ksp, x, rel, wall = assembled_solve(
+        comm, A, "bcgs", "bjacobi", rtol,
+        options=["-pc_bjacobi_blocks", "128", "-pc_setup_device", "auto"])
+    pc = ksp.get_pc()
+    gate = pcmod.gate_fallbacks["block"] - g0
+    check(pc.sub_solve == "dense",
+          f"bjacobi with 128 blocks took {pc.sub_solve!r} blocks")
+    check(pc.setup_mode == "device" or gate > 0,
+          f"bjacobi setup ran on {pc.setup_mode!r} with no gate rejection")
+    emit("assembled",
+         f"BCGS+bjacobi(128 dense) convdiff2d({nx}) fp64 rtol {rtol:g}",
+         iters=res.iterations, relres=fmt(rel), wall_s=f"{wall:.2f}",
+         pc_setup=pc.setup_mode, gate_fallbacks=gate)
+    ilu_check(comm)
 
     out = io.StringIO()
     with contextlib.redirect_stdout(out):

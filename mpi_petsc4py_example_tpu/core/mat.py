@@ -89,10 +89,20 @@ class Mat:
         ``assembly_breakdown`` attributes real time, not async-dispatch
         slack spilled into whatever the caller times next.
         """
+        from ..telemetry import spans as _telemetry
+        comm = as_comm(comm)
+        with _telemetry.span("mat.assemble", rows=int(size[0])) as sp:
+            m = cls._from_csr(comm, size, csr, dtype)
+            sp.set_attr("format", "dia" if m.dia_vals is not None
+                        else "ell")
+            return m
+
+    @classmethod
+    def _from_csr(cls, comm, size, csr, dtype) -> "Mat":
+        """The body of :meth:`from_csr`, inside its ``mat.assemble`` span."""
         import time as _time
 
         from ..utils import native
-        comm = as_comm(comm)
         nrows, ncols = int(size[0]), int(size[1])
         t0 = _time.perf_counter()
         indptr = np.asarray(csr[0], dtype=np.int64)

@@ -7,7 +7,8 @@ pc.setFactorSolverType('mumps')`` (``test.py:40-43``). Types provided:
 * ``jacobi`` — inverse-diagonal scaling; a sharded elementwise multiply.
 * ``bjacobi``— block Jacobi: each mesh device owns its local diagonal block's
   inverse (the TPU analog of PETSc's per-rank PCBJACOBI+LU); apply is a
-  batched dense matvec on the MXU.
+  batched dense matvec on the MXU. Blocks past the dense cap on five-point
+  DIA operators take PETSc's default ILU(0) sub-solve (solvers/bjilu.py).
 * ``lu`` / ``cholesky`` — full direct factorization. This is the MUMPS-slot
   replacement (``test.py:43``): no multifrontal sparse direct solver exists
   for TPU (SURVEY.md §7.4), so direct solves factorize on the host in fp64
@@ -103,6 +104,9 @@ class PC:
                                     # a placement-capable kind is set up
         self.setup_breakdown = None  # device-mode phase split (extract_s /
                                      # invert_s), for the benchmark artifact
+        self.sub_solve = None       # bjacobi's block solve: 'dense' |
+                                    # 'ilu0' (solvers/bjilu.py)
+        self.sub_blocks = 0         # ... and its block count, all devices
         self._amg = None
         # PCSHELL: user apply (full-vector jax-traceable callable) + a uid so
         # compiled-program caches distinguish different shell functions
@@ -238,8 +242,12 @@ class PC:
             return self
         from ..telemetry import spans as _telemetry
         with _telemetry.span("pc.setup", pc_type=self._type,
-                             n=int(mat.shape[0])):
-            return self._set_up_build(mat, build_key)
+                             n=int(mat.shape[0])) as sp:
+            self._set_up_build(mat, build_key)
+            if self.sub_solve is not None:
+                sp.set_attrs(sub_solve=self.sub_solve,
+                             blocks=self.sub_blocks)
+            return self
 
     def _set_up_build(self, mat, build_key):
         """The actual factor build/placement (the ``pc.setup`` span body
@@ -253,6 +261,8 @@ class PC:
         self._hostlu = None
         self.setup_mode = None
         self.setup_breakdown = None
+        self.sub_solve = None
+        self.sub_blocks = 0
         if t == "none":
             self._arrays = ()
         elif t == "jacobi":
@@ -382,6 +392,8 @@ class PC:
             return "lu"
         if t == "amg":
             return "gamg"
+        if t == "bjacobi" and self.sub_solve == "ilu0":
+            return "bjacobi_ilu0"
         # sor/ssor/ilu/icc all apply as one per-device dense block matvec —
         # the same kernel shape as block Jacobi, different block algebra
         if t in ("sor", "ssor", "ilu", "icc"):
@@ -428,7 +440,7 @@ class PC:
             return ()
         if k == "jacobi":
             return (P(axis),)
-        if k == "bjacobi":
+        if k in ("bjacobi", "bjacobi_ilu0"):
             return (P(axis),)
         if k == "asm":
             return (P(axis),)
@@ -484,6 +496,10 @@ class PC:
                                       r.reshape(nb, bs),
                                       comm.platform).reshape(-1)
             return apply
+        if k == "bjacobi_ilu0":
+            from .bjilu import apply
+            interpret = comm.platform != "tpu"
+            return lambda arrs, r: apply(arrs, r, interpret)
         if k == "asm":
             ov = int(self.asm_overlap)
             ndev = comm.size
@@ -610,7 +626,8 @@ class PC:
         The diagonal kinds broadcast over the trailing RHS axis; the MXU
         block kinds (bjacobi and the sor/ssor/ilu/icc family that shares
         its kernel shape) take the trailing axis straight through the
-        batched matmul; dense lu gathers the whole RHS block in ONE
+        batched matmul; bjacobi's ILU(0) blocks sweep each column,
+        batched; dense lu gathers the whole RHS block in ONE
         collective. Per-apply collective count never grows with k.
         """
         k = self.kind
@@ -631,6 +648,10 @@ class PC:
                     "bij,bjc->bic", binv, R.reshape(nb, bs, R.shape[1]),
                     comm.platform).reshape(-1, R.shape[1])
             return apply
+        if k == "bjacobi_ilu0":
+            from .bjilu import apply_many
+            interpret = comm.platform != "tpu"
+            return lambda arrs, R: apply_many(arrs, R, interpret)
         if k == "lu":
             def apply(arrs, R):
                 minv = arrs[0]   # replicated (n_pad, n_pad) inverse
@@ -668,7 +689,9 @@ class PC:
         applies (none/jacobi) are symmetric and reuse the forward closure;
         block kinds (bjacobi/sor/ssor/ilu/icc) and lu/cholesky transpose
         their shipped explicit inverses ((B⁻¹)ᵀ = (Bᵀ)⁻¹ — one transposed
-        batched matvec); composite-additive sums its children's transposes;
+        batched matvec); bjacobi's ILU(0) blocks run their two sweeps on
+        the shifted factors of ``(LU)ᵀ`` (``bjilu.transpose``);
+        composite-additive sums its children's transposes;
         shell uses the user's ``set_shell_apply_transpose`` function.
         mg is symmetric by construction (R = (1/2)Pᵀ, equal pre/post
         smoothing) so its forward apply is reused;
@@ -705,6 +728,11 @@ class PC:
                 return jnp.einsum("bij,bi->bj", binv,
                                   r.reshape(nb, bs)).reshape(-1)
             return apply_t
+        if k == "bjacobi_ilu0":
+            from .bjilu import apply, transpose
+            interpret = comm.platform != "tpu"
+            return lambda arrs, r: apply((transpose(arrs[0]),), r,
+                                         interpret)
         if k == "lu":
             def apply_t(arrs, r):
                 minv = arrs[0]  # replicated (n_pad, n_pad) inverse of A
@@ -818,12 +846,34 @@ def _build_bjacobi(comm: DeviceComm, mat: Mat, blocks: int = 0,
     (counted in :data:`gate_fallbacks`) and the complex path. A device
     compile or runtime error propagates: it is never hidden behind the
     host path.
+
+    Where a dense block would pass the dense cap (with the block count
+    left to the library: more rows on a device than the cap), a real
+    five-point DIA operator takes PETSc's default sub-solve instead:
+    ILU(0) blocks of whole grid lines, as many as ``-pc_bjacobi_blocks``
+    asks or about ``bjilu.BLOCK_ROWS`` rows each, factored on the host
+    and applied on the device (solvers/bjilu.py, PC kind
+    ``bjacobi_ilu0``). ``owner.sub_solve`` says which ran.
     """
     import scipy.linalg
     _require_assembled(mat, "bjacobi")
     n = mat.shape[0]
     lsize = comm.local_size(n)
-    nb = _bjacobi_block_count(lsize, comm.size, int(blocks))
+    asked = (_bjacobi_block_count(lsize, comm.size, int(blocks))
+             if int(blocks) > 0 else 0)
+    if lsize // max(asked, 1) > _DENSE_CAP:
+        from . import bjilu
+        built = bjilu.build(comm, mat, asked)
+        if built is not None:
+            stack, info = built
+            if owner is not None:
+                owner.setup_mode = "host"
+                owner.setup_breakdown = info
+                owner.sub_solve, owner.sub_blocks = "ilu0", info["blocks"]
+            return (stack,)
+    nb = asked or _bjacobi_block_count(lsize, comm.size, 0)
+    if owner is not None:
+        owner.sub_solve, owner.sub_blocks = "dense", comm.size * nb
     if lsize // nb > _DENSE_CAP:
         raise ValueError(
             f"PC 'bjacobi' blocks are dense ({lsize // nb}x{lsize // nb}); "

@@ -48,9 +48,15 @@ NAMES = {
     "compile.group": ("span", "compile.* spans recorded with no span "
                               "open, one after another: one flight-ring "
                               "entry for the burst (children)"),
+    # ---- spans: assembly (core/mat.py) ----
+    "mat.assemble": ("span", "Mat.from_csr: CSR validation, the ELL and "
+                             "DIA layouts and their one device placement "
+                             "(attrs rows, format 'dia' | 'ell')"),
     # ---- spans: PC / EPS / refinement ----
     "pc.setup": ("span", "preconditioner factor build/placement (covers "
-                         "the MG/GAMG hierarchy build — the MG entry)"),
+                         "the MG/GAMG hierarchy build — the MG entry; "
+                         "bjacobi adds attrs sub_solve 'dense' | 'ilu0' "
+                         "and blocks)"),
     "eps.solve": ("span", "one EPS.solve eigensolve"),
     "refine.outer": ("span", "RefinedKSP outer fp64 refinement loop"),
     "refine.step": ("span", "one outer correction step (inner solve + "

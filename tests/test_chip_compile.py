@@ -164,3 +164,28 @@ def test_mg_vcycle_kernels_named_by_level(one_chip):
         assert f"%{name}." in text, name
     assert "%stencil3d_smooth_pair_pallas." not in text
     assert "stencil3d_prolong_add_pallas_l2" not in text
+
+
+@pytest.mark.parametrize("form", ["apply", "transpose", "many"])
+def test_bjacobi_ilu0_apply_2048(one_chip, form):
+    """PC bjacobi's ILU(0) block apply (solvers/bjilu.py) at 2048^2 in
+    fp64: 64 blocks of 32 lines, the double-f32 Pallas kernel over 8
+    groups of 8 blocks inside its VMEM limit, and the whole apply within
+    HBM; also on the transposed stack (BiCG) and batched over 4 columns
+    (``KSP.solve_many``)."""
+    from mpi_petsc4py_example_tpu.solvers.bjilu import (apply, apply_many,
+                                                        group_size,
+                                                        transpose)
+    assert group_size((64, 5, 32, 2048), 1) == 8
+    coef = jax.ShapeDtypeStruct((8, 10, 32, 8, 2048), jnp.float32,
+                                sharding=one_chip)
+    shape = (2048 * 2048, 4) if form == "many" else (2048 * 2048,)
+    r = jax.ShapeDtypeStruct(shape, jnp.float64, sharding=one_chip)
+    fn = {"apply": apply, "many": apply_many,
+          "transpose": lambda a, v: apply((transpose(a[0]),), v)}[form]
+    c = jax.jit(fn).lower((coef,), r).compile()
+    assert "bjacobi_ilu0_pallas" in c.as_text()
+    m = c.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert used < HBM_BYTES, used

@@ -116,6 +116,57 @@ class TestBCGS:
         assert res.converged, res
         np.testing.assert_allclose(x, x_true, rtol=1e-6, atol=1e-8)
 
+    @pytest.mark.parametrize("cos, lifted", [(0.9, False), (0.3, True),
+                                             (0.0, False)])
+    @pytest.mark.parametrize("scale, dtype", [(1.0, np.float64),
+                                              (1e-12, np.float32)])
+    def test_omega_floor(self, cos, lifted, scale, dtype):
+        # omega = (t,s)/(t,t) is scaled to kappa ||s||/||t|| where
+        # |cos(t,s)| < kappa, and not at a breakdown (cos 0); at 1e-12 the
+        # float32 product (t,t)(s,s) underflows, the floor must not
+        from mpi_petsc4py_example_tpu.solvers.krylov import (OMEGA_KAPPA,
+                                                             _limit_omega)
+        s = np.array([1.0, 0.0]) * scale
+        t = 2.0 * np.array([cos, np.sqrt(1 - cos * cos)]) * scale
+        ts, tt, ss = (np.asarray(v, dtype) for v in (t @ s, t @ t, s @ s))
+        got = float(_limit_omega(ts / tt, ts, tt, ss))
+        want = OMEGA_KAPPA / 2 if lifted else cos / 2
+        assert got == pytest.approx(want, rel=1e-5)
+
+    @pytest.mark.parametrize("ksp_type", ["bcgs", "fbcgsr"])
+    def test_omega_floor_iterates(self, comm8, ksp_type):
+        # the kernels take the iterations of a plain numpy BiCGStab with the
+        # same floor, on a system where the floor acts
+        from mpi_petsc4py_example_tpu.solvers.krylov import OMEGA_KAPPA
+        A = convdiff2d(16, beta=0.6)
+        _, b = manufactured(A, seed=4)
+        dinv = 1.0 / A.diagonal()
+        x, r = np.zeros_like(b), b.copy()
+        rhat, p, v = r.copy(), np.zeros_like(b), np.zeros_like(b)
+        rho = alpha = omega = 1.0
+        lifts = 0
+        for k in range(1, 500):
+            rho_new = rhat @ r
+            p = r + rho_new / rho * alpha / omega * (p - omega * v)
+            v = A @ (dinv * p)
+            alpha = rho_new / (rhat @ v)
+            s = r - alpha * v
+            t = A @ (dinv * s)
+            omega = (t @ s) / (t @ t)
+            cos = abs(t @ s) / (np.linalg.norm(t) * np.linalg.norm(s))
+            if cos < OMEGA_KAPPA:
+                omega *= OMEGA_KAPPA / cos
+                lifts += 1
+            x += dinv * (alpha * p + omega * s)
+            r = s - omega * t
+            rho = rho_new
+            if np.linalg.norm(r) <= 1e-10 * np.linalg.norm(b):
+                break
+        assert lifts > 0
+        _, res, _ = solve(comm8, A, b, ksp_type, "jacobi", rtol=1e-10)
+        assert res.converged, res
+        assert abs(res.iterations - k) <= 1, (res.iterations, k)
+
 
 class TestDirect:
     def test_preonly_lu_reference_system(self, comm):

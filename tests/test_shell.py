@@ -298,15 +298,19 @@ class TestBJacobiBlocks:
 
     def test_auto_split_over_cap(self, comm1, monkeypatch):
         """Past the dense cap the default splits instead of failing (the
-        cfg4-on-one-device path)."""
+        cfg4-on-one-device path). A 3D operator: a 2D five-point one
+        takes ILU(0) blocks there (tests/test_bjacobi_ilu.py)."""
+        from mpi_petsc4py_example_tpu.models import poisson3d_csr
         from mpi_petsc4py_example_tpu.solvers import pc as pcmod
         monkeypatch.setattr(pcmod, "_DENSE_CAP", 32)
         monkeypatch.setattr(pcmod, "_AUTO_BLOCK_TARGET", 16)
-        A = poisson2d(8)          # lsize 64 > cap 32 → auto 2 blocks
+        A = poisson3d_csr(4)      # lsize 64 > cap 32 → auto 4 blocks
         x_true, b = manufactured(A)
         M = tps.Mat.from_scipy(comm1, A)
-        x, res, _ = run_ksp(comm1, M, b, "cg", pc="bjacobi")
+        x, res, ksp = run_ksp(comm1, M, b, "cg", pc="bjacobi")
         assert res.converged
+        assert (ksp.get_pc().sub_solve, ksp.get_pc().sub_blocks) == \
+            ("dense", 4)
         np.testing.assert_allclose(x, x_true, rtol=1e-7, atol=1e-9)
 
 
