@@ -107,17 +107,13 @@ def _op_key(op):
     return (op.shape[0], str(op.dtype), op.program_key())
 
 
-def _aot_operand_shapes(op, inner=None):
-    """Shape/dtype fingerprint of the device operand arrays — part of the
-    AOT blob key. ``_op_key`` pins the logical operator (n, dtype, layout
-    kind) but NOT the operand geometry an exported program is specialized
-    to (e.g. the ELL width K, the DIA diagonal count): two same-n
-    operators with different sparsity would otherwise collide on one blob
-    and the load-time program would reject the other's arrays."""
-    leaves = list(jax.tree_util.tree_leaves(op.device_arrays()))
-    if inner is not None:
-        leaves += jax.tree_util.tree_leaves(inner.device_arrays())
-    return tuple((tuple(a.shape), str(a.dtype)) for a in leaves)
+def _operand_shapes(op, inner=None):
+    """The operand geometry part of an AOT blob key (utils/aot.
+    operand_shapes): ``_op_key`` pins the logical operator but not, e.g.,
+    the ELL width K an exported program is specialized to."""
+    return _aot.operand_shapes(
+        op.device_arrays(),
+        inner.device_arrays() if inner is not None else ())
 
 
 def _facto_steps(spmv, b_apply, axis, ncv):
@@ -195,8 +191,7 @@ def _build_seed_facto_program(comm: DeviceComm, op, ncv: int, inner=None):
         in_specs=(op_specs, b_specs, P(axis)),
         out_specs=(P(None, axis), P())))
     prog = _aot.wrap("seedfacto", comm,
-                     key[3:] + (_aot_operand_shapes(op, inner),), prog,
-                     code=_aot.source_fingerprint(__file__))
+                     key[3:] + (_operand_shapes(op, inner),), prog)
     _PROGRAM_CACHE[key] = prog
     return prog
 
@@ -236,8 +231,7 @@ def _build_restart_facto_program(comm: DeviceComm, op, ncv: int, inner=None):
         in_specs=(op_specs, b_specs, P(None, axis), P(), P(), P()),
         out_specs=(P(None, axis), P())))
     prog = _aot.wrap("restartfacto", comm,
-                     key[3:] + (_aot_operand_shapes(op, inner),), prog,
-                     code=_aot.source_fingerprint(__file__))
+                     key[3:] + (_operand_shapes(op, inner),), prog)
     _PROGRAM_CACHE[key] = prog
     return prog
 

@@ -31,6 +31,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.spmv import ell_spmv_local
 from ..resilience import faults as _faults
 from ..resilience import abft as _abft
+from ..utils import aot
 from ..utils.dtypes import is_complex
 from ..parallel.mesh import DeviceComm, faulted_psum
 from ..utils.convergence import ConvergedReason as CR
@@ -2196,6 +2197,13 @@ def build_ksp_program(comm: DeviceComm, ksp_type: str, pc, operator,
     CONSUMED by the call (KSP.solve rebinds ``x.data`` to the program's
     output). Silently off on backends that cannot alias
     (:func:`donation_supported`).
+
+    The program is served through the export cache (utils/aot.wrap,
+    kind ``ksp``): a fresh process loads its StableHLO instead of
+    tracing and lowering it, and the returned :class:`aot.Program` says
+    which it did (``aot``). Not for a key that holds a fault plan's
+    nonce, a live monitor or a shell callback's uid; those, and every
+    program under ``TPU_SOLVE_AOT=0``, are the plain jit.
     """
     axis = comm.axis
     n = operator.shape[0]
@@ -2265,11 +2273,13 @@ def build_ksp_program(comm: DeviceComm, ksp_type: str, pc, operator,
     # a corrupted comm.psum baked into the jaxpr) is never cached into —
     # or served from — the fault-free program set.
     donate_k = bool(donate) and donation_supported()
+    trace_nonce = _faults.trace_key()
+    aot_on = aot.aot_enabled()
     key = (comm.mesh, axis, ksp_type, pc.program_key(), n, prec.key(),
            restart_k, monitored, zero_guess, operator.program_key(),
            nullspace_dim, aug_k, ell_k, unroll_k, natural_k, cap_k, live_k,
            true_res_k, abft_k, abft_pc_k, bool(rr), donate_k, sstep_k,
-           _faults.trace_key())
+           trace_nonce, aot_on)
     cached = _PROGRAM_CACHE.get(key)
     if cached is not None:
         return cached
@@ -2664,8 +2674,18 @@ def build_ksp_program(comm: DeviceComm, ksp_type: str, pc, operator,
         out_specs = out_specs + (P(), P(), P(axis))
     if true_res_k:
         out_specs = out_specs + (P(), P())
+    dn = (x0_idx,) if donate_k else ()
     prog = jax.jit(comm.shard_map(local_fn, in_specs, out_specs),
-                   donate_argnums=(x0_idx,) if donate_k else ())
+                   donate_argnums=dn)
+    # the export cache (utils/aot): a later process loads this program's
+    # StableHLO instead of tracing and lowering it, unless the key holds
+    # something valid in this process only: a fault plan's nonce, a live
+    # monitor's host callbacks (a shell callback's uid: aot.wrap)
+    if aot_on and trace_nonce is None and not live_k:
+        prog = aot.wrap("ksp", comm,
+                        key[1:] + (aot.operand_shapes(
+                            operator.device_arrays(), pc.device_arrays()),),
+                        prog, donate_argnums=dn)
     _PROGRAM_CACHE[key] = prog
     return prog
 
@@ -2820,7 +2840,6 @@ def build_ksp_program_many(comm: DeviceComm, ksp_type: str, pc, operator,
             f"batched multi-RHS programs support KSP 'cg'/'pipecg'/"
             f"'sstep' (the block-CG plans); {ksp_type!r} solves route "
             "through the sequential fallback (KSP.solve_many)")
-    from ..utils import aot
     axis = comm.axis
     n = operator.shape[0]
     dtype = operator.dtype
@@ -3022,8 +3041,6 @@ def build_ksp_program_many(comm: DeviceComm, ksp_type: str, pc, operator,
         # appends its own mesh/jax-version/x64 fingerprint) — nrhs is in
         # there, so each batch width gets its own shape-specialized blob
         prog = aot.wrap("ksp_many", comm, key[1:], prog,
-                        code=aot.source_fingerprint(__file__,
-                                                    _plans.__file__),
                         donate_argnums=dn)
     _PROGRAM_CACHE_MANY[key] = prog
     return prog

@@ -30,6 +30,7 @@ from ..parallel.mesh import as_comm
 from ..resilience import abft as _abft_defaults
 from ..resilience import faults as _faults
 from ..telemetry import spans as _telemetry
+from ..utils import aot as _aot
 from ..utils.convergence import (BatchedSolveResult, ConvergedReason,
                                  SolveResult)
 from ..utils.errors import SilentCorruptionError, wrap_device_errors
@@ -693,7 +694,7 @@ class KSP:
         cs_args, abft_pc_on = ((), False)
         if guard:
             cs_args, abft_pc_on = self._guard_checksums(mat, pc, op_dt)
-        with _telemetry.span("ksp.setup"):
+        with _telemetry.span("ksp.setup") as setup_span:
             prog = build_ksp_program(
                 comm, self._type, pc, mat,
                 restart=self.restart,
@@ -714,6 +715,7 @@ class KSP:
                 abft_pc=abft_pc_on,
                 rr=guard and self._effective_replacement() > 0,
                 donate=True, sstep_s=self.sstep_s)
+            setup_span.set_attr("aot", _aot.status(prog))
         # host scalars travel with the execute call — no extra device
         # round-trips.
         # Tolerances are always REAL-typed: for complex operators the
@@ -825,6 +827,8 @@ class KSP:
                         dt.type(rtol * margin), dt.type(atol * margin),
                         dt.type(divtol), np.int32(self.max_it),
                         *guard_scalars)
+                # a loaded program that rejected the operands re-traced
+                setup_span.set_attr("aot", _aot.status(prog))
                 xd, iters, rnorm, reason, hist = out[:5]
                 # rebind the caller's vector IMMEDIATELY: the donated x0
                 # buffer is gone, so any exit path from here on (a raising

@@ -165,16 +165,6 @@ def _reason_outer(conv, rn, atol, brk, ibrk, stag_reason):
                   CR.DIVERGED_MAX_IT)).astype(jnp.int32)
 
 
-def _aot_code():
-    from ..utils import aot
-    from . import krylov as _krylov
-    # the fused body is assembled from THREE modules' source: this
-    # builder, the plan loops, and krylov's guard/closure helpers — an
-    # edit to any of them changes the traced program
-    return aot.source_fingerprint(__file__, _plans.__file__,
-                                  _krylov.__file__)
-
-
 def build_megasolve_program(comm: DeviceComm, ksp_type: str, pc, inner_op,
                             outer_op=None, *, zero_guess: bool = True,
                             abft: bool = False, abft_pc: bool = False,
@@ -469,7 +459,7 @@ def build_megasolve_program(comm: DeviceComm, ksp_type: str, pc, inner_op,
                    donate_argnums=dn)
     if aot_on:
         prog = aot.wrap("megasolve", comm, key[1:], prog,
-                        code=_aot_code(), donate_argnums=dn)
+                        donate_argnums=dn)
     _MEGASOLVE_CACHE[key] = prog
     return prog
 
@@ -766,7 +756,6 @@ def build_megasolve_program_many(comm: DeviceComm, ksp_type: str, pc,
     prog = jax.jit(comm.shard_map(local_fn, in_specs, out_specs),
                    donate_argnums=dn)
     if aot_on:
-        prog = aot.wrap(kind, comm, key[1:], prog,
-                        code=_aot_code(), donate_argnums=dn)
+        prog = aot.wrap(kind, comm, key[1:], prog, donate_argnums=dn)
     cache[key] = prog
     return prog

@@ -30,7 +30,12 @@ NAMES = {
     "ksp.solve": ("span", "one KSP.solve call: setup -> dispatch -> fetch "
                           "(re-entries nest as child ksp.solve spans)"),
     "ksp.solve_many": ("span", "one batched KSP.solve_many block launch"),
-    "ksp.setup": ("span", "PC set_up + solve-program build/AOT-load"),
+    "ksp.setup": ("span", "PC set_up + solve-program build/AOT-load "
+                          "(the build's span has attr aot: 'hit' "
+                          "loaded from a blob, 'miss' exported at the "
+                          "first call, 'off' not exportable or "
+                          "TPU_SOLVE_AOT=0, 'fallback' a loaded program "
+                          "re-traced; utils/aot.py)"),
     "ksp.dispatch": ("span", "the compiled solve program's execute call"),
     "ksp.fetch": ("span", "the batched D2H result fetch"),
     "ksp.verify": ("span", "the true-residual gate decision + re-entries"),

@@ -51,3 +51,14 @@ def clean_options():
     tps.global_options().clear()
     yield
     tps.global_options().clear()
+
+
+@pytest.fixture(autouse=True)
+def aot_cache_dir(tmp_path_factory, monkeypatch):
+    """Export-cache blobs (utils/aot) in a temporary directory of each
+    test's own: no test reads a blob that an earlier run left in the
+    checkout, or one that another test exported from a function it had
+    patched."""
+    d = str(tmp_path_factory.mktemp("aot"))
+    monkeypatch.setenv("TPU_SOLVE_AOT_DIR", d)
+    return d

@@ -1,11 +1,13 @@
-"""AOT export/deserialize of the fixed-shape EPS programs (utils/aot).
+"""AOT export/deserialize of solve programs (utils/aot).
 
-Round-6 cold-start lever: a fresh cfg2-style process pays tracing +
-lowering for the seed+facto and compress+facto programs; utils/aot
-serializes each program's StableHLO once (jax.export) and later processes
-deserialize it instead of re-tracing. These tests pin the disk round trip
-(bit-identical results), the key discipline (mesh/code fingerprints), the
-silent fallback on corrupt blobs, and the TPU_SOLVE_AOT=0 kill switch.
+A fresh process pays tracing + lowering for every program it builds;
+utils/aot serializes each program's StableHLO once (jax.export) and later
+processes deserialize it instead of re-tracing. These tests pin, for the
+fixed-shape EPS programs and the single-RHS KSP solve programs, the disk
+round trip (bit-identical results, no trace of the solve body when
+loaded), the key discipline (mesh and package-source fingerprints, no
+blob for a key that holds a process-local identity), the silent fallback
+on corrupt blobs, and the TPU_SOLVE_AOT=0 kill switch.
 """
 
 import os
@@ -106,7 +108,7 @@ class TestAotRoundTrip:
     def test_stale_blob_shape_mismatch_falls_back(self, comm8, aot_dir):
         """A blob whose key_parts failed to pin some operand geometry must
         never crash the caller: the loaded program's shape rejection falls
-        back to the traced program (and re-exports this geometry)."""
+        back to the traced program, and says so."""
         import jax
         import jax.numpy as jnp
         f1 = jax.jit(lambda x: x * 2.0)
@@ -115,8 +117,10 @@ class TestAotRoundTrip:
         assert len(_blobs(aot_dir)) == 1
         f2 = jax.jit(lambda x: x * 2.0)
         w2 = aot.wrap("collide", comm8, ("unpinned",), f2)  # loads blob
+        assert w2.aot == "hit"
         out = w2(jnp.arange(4.0))             # (4,) != (8,): must not raise
         np.testing.assert_allclose(np.asarray(out), np.arange(4.0) * 2.0)
+        assert w2.aot == "fallback"
 
     def test_key_pins_operand_geometry(self, comm8, aot_dir):
         """Two same-n, same-layout-kind operators with different ELL
@@ -158,17 +162,185 @@ class TestAotGates:
         assert all(not f.endswith(".tmp") for f in _blobs(aot_dir))
 
     def test_source_fingerprint(self):
-        fp = aot.source_fingerprint(eps_mod.__file__)
+        """Every blob key holds the package-wide source fingerprint:
+        computed once per process, the same on every call."""
+        fp = aot.package_fingerprint()
         assert len(fp) == 64
-        assert fp == aot.source_fingerprint(eps_mod.__file__)  # cached
-        # unreadable source degrades to hashing the path — stable, and
-        # never colliding with a real source hash
-        missing = aot.source_fingerprint("/nonexistent/mod.py")
-        assert len(missing) == 64 and missing != fp
-        assert missing == aot.source_fingerprint("/nonexistent/mod.py")
-        # multi-file form: extra kernel-body modules change the digest
-        # (the ksp_many blobs hash krylov.py AND cg_plans.py — an edit
-        # to the plan module must never serve a stale pre-edit program)
-        import mpi_petsc4py_example_tpu.solvers.cg_plans as plans_mod
-        both = aot.source_fingerprint(eps_mod.__file__, plans_mod.__file__)
-        assert len(both) == 64 and both != fp
+        assert fp == aot.package_fingerprint()              # cached
+        assert fp == aot.package_fingerprint(aot.PACKAGE_DIR)
+
+
+# ---------------------------------------------------------------------------
+# single-RHS KSP solve programs (krylov.build_ksp_program, kind "ksp")
+# ---------------------------------------------------------------------------
+
+def _walk(tree, out):
+    out.append(tree)
+    for c in tree.get("children", ()):
+        _walk(c, out)
+    return out
+
+
+@pytest.fixture()
+def spans():
+    """Telemetry on for the test; returns a reader of the spans so far."""
+    from mpi_petsc4py_example_tpu import telemetry
+    telemetry.flight_recorder.clear()
+    telemetry.enable(flight_len=1 << 16)
+
+    def read():
+        return [s for t in telemetry.flight_recorder.spans()
+                for s in _walk(t, [])]
+    yield read
+    telemetry.disable()
+    telemetry.flight_recorder.clear()
+
+
+def _ksp_case(case, monkeypatch):
+    """``(operator, ksp_type, pc_type)`` of one cell-like solve, small."""
+    from mpi_petsc4py_example_tpu.models import StencilPoisson3D, convdiff2d
+    comm = tps.DeviceComm(n_devices=1)
+    if case == "bcgs_bjacobi_ilu0":
+        from mpi_petsc4py_example_tpu.solvers import bjilu
+        from mpi_petsc4py_example_tpu.solvers import pc as pcmod
+        # past a lowered dense cap: 16 ILU(0) blocks of 2 lines, the XLA
+        # sweeps (the TPU kernel's layout is the chip's)
+        monkeypatch.setattr(pcmod, "_DENSE_CAP", 64)
+        monkeypatch.setattr(bjilu, "BLOCK_ROWS", 64)
+        monkeypatch.setattr(bjilu, "use_kernel", lambda platform, dtype:
+                            False)
+        return tps.Mat.from_scipy(comm, convdiff2d(32).tocsr()), "bcgs", \
+            "bjacobi"
+    return StencilPoisson3D(comm, 16, 16, 16), "cg", case.split("_")[1]
+
+
+def _ksp_solve(op, ksp_type, pc_type, guess):
+    """One gated solve from x0 = 0 or a nonzero guess: ``(x,
+    iterations, the PC's kind)``."""
+    ksp = tps.KSP().create(op.comm)
+    ksp.set_operators(op)
+    ksp.set_type(ksp_type)
+    ksp.get_pc().set_type(pc_type)
+    ksp.set_tolerances(rtol=1e-8, max_it=2000)
+    ksp.set_true_residual_check(True)
+    n = op.shape[0]
+    x, b = op.get_vecs()
+    b.set_global(np.random.default_rng(3).random(n))
+    if guess == "nonzero":
+        x.set_global(np.full(n, 0.5))
+    ksp.set_initial_guess_nonzero(guess == "nonzero")
+    res = ksp.solve(b, x)
+    assert res.converged, res
+    return x.to_numpy(), int(res.iterations), ksp.get_pc().kind
+
+
+def _builds(spans):
+    return [s["attrs"]["aot"] for s in spans
+            if s["name"] == "ksp.setup" and "aot" in s["attrs"]]
+
+
+class TestKspProgram:
+    @pytest.mark.parametrize("guess", ["zero", "nonzero"])
+    @pytest.mark.parametrize("case", ["cg_jacobi", "cg_mg",
+                                      "bcgs_bjacobi_ilu0"])
+    def test_export_then_load(self, case, guess, aot_dir, spans,
+                              monkeypatch):
+        """The first solve exports its program; a second process (a
+        cleared program cache) loads it: the same x, bit for bit, the
+        same iterations, and no trace of the solve body."""
+        from mpi_petsc4py_example_tpu.solvers import krylov
+        op, ksp_type, pc_type = _ksp_case(case, monkeypatch)
+        krylov._PROGRAM_CACHE.clear()
+        x1, it1, kind = _ksp_solve(op, ksp_type, pc_type, guess)
+        assert kind == {"cg_jacobi": "jacobi", "cg_mg": "mg",
+                        "bcgs_bjacobi_ilu0": "bjacobi_ilu0"}[case]
+        cold = spans()
+        assert _builds(cold) and set(_builds(cold)) == {"miss"}
+        assert any(s["name"] == "compile.trace"
+                   and "local_fn" in s["attrs"].get("fun_name", "")
+                   for s in cold)
+        assert len(_blobs(aot_dir)) == len(_builds(cold))
+
+        krylov._PROGRAM_CACHE.clear()
+        n_cold = len(cold)
+        x2, it2, _ = _ksp_solve(op, ksp_type, pc_type, guess)
+        warm = spans()[n_cold:]
+        assert set(_builds(warm)) == {"hit"}
+        assert it2 == it1
+        np.testing.assert_array_equal(x2, x1)
+        traced = [s["attrs"].get("fun_name", "") for s in warm
+                  if s["name"] == "compile.trace"]
+        assert not [f for f in traced if "local_fn" in f], traced
+        krylov._PROGRAM_CACHE.clear()
+
+    @pytest.mark.parametrize("local", ["shell_pc", "live_monitor",
+                                       "fault_plan"])
+    def test_process_local_key_writes_no_blob(self, local, aot_dir, spans):
+        """A program whose key holds something valid in this process
+        only is never exported, nor loaded: it keeps the traced jit."""
+        from mpi_petsc4py_example_tpu.models import poisson2d_csr
+        from mpi_petsc4py_example_tpu.resilience import faults
+        from mpi_petsc4py_example_tpu.solvers import krylov
+        comm = tps.DeviceComm(n_devices=1)
+        A = poisson2d_csr(8)
+        M = tps.Mat.from_scipy(comm, A)
+        ksp = tps.KSP().create(comm)
+        ksp.set_operators(M)
+        ksp.set_type("cg")
+        ksp.set_tolerances(rtol=1e-8, max_it=200)
+        plan = "spmv.result=bitflip:at=100000"      # armed, never fires
+        if local == "shell_pc":
+            pc = ksp.get_pc()
+            pc.set_type("shell")
+            pc.set_shell_apply(lambda r: r)
+        elif local == "live_monitor":
+            assert krylov.live_monitor_supported(comm)
+            ksp.set_monitor(lambda *a: None)
+        x, b = M.get_vecs()
+        b.set_global(A @ np.ones(A.shape[0]))
+        krylov._PROGRAM_CACHE.clear()
+        if local == "fault_plan":
+            with faults.inject_faults(plan):
+                res = ksp.solve(b, x)
+        else:
+            res = ksp.solve(b, x)
+        assert res.converged
+        assert _blobs(aot_dir) == []
+        assert set(_builds(spans())) == {"off"}
+        krylov._PROGRAM_CACHE.clear()
+
+    def test_process_local_keys(self, comm8, aot_dir):
+        """Shell uids are found at any depth of a key (a composite PC's
+        children, a spectral transform's operator), and no program kind
+        keyed on one goes through the export cache."""
+        sentinel = object()
+        for kind in ("ksp", "ksp_many", "megasolve", "seedfacto"):
+            assert aot.wrap(kind, comm8, ("cg", ("shellmat", 1)),
+                            sentinel) is sentinel
+        _process_local = aot._process_local
+        assert _process_local(("cg", ("shell", 3)))
+        assert _process_local(("cg", ("composite", "additive", (),
+                                      ("jacobi",), ("shell", 1))))
+        assert _process_local((("st", "shift", False, ("shellmat", 2),
+                                None),))
+        assert not _process_local(("cg", ("jacobi",), ("dia", (-1, 0, 1)),
+                                   None, "shell"))
+
+
+@pytest.mark.parametrize("module", [
+    "solvers/krylov.py", "solvers/cg_plans.py", "solvers/pc.py",
+    "solvers/bjilu.py", "solvers/mg.py", "ops/pallas_stencil.py",
+    "core/mat.py", "models/stencil.py"])
+def test_package_fingerprint_sees_every_module(module, tmp_path):
+    """One byte changed in any module a solve program traces changes the
+    fingerprint every blob key holds: the edit misses the cache."""
+    import shutil
+    root = tmp_path / "pkg"
+    shutil.copytree(aot.PACKAGE_DIR, root,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = aot.package_fingerprint(str(root))
+    assert before == aot.package_fingerprint(aot.PACKAGE_DIR)
+    with open(root / module, "ab") as fh:
+        fh.write(b"\n")
+    aot.package_fingerprint.cache_clear()
+    assert aot.package_fingerprint(str(root)) != before

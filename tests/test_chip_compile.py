@@ -189,3 +189,35 @@ def test_bjacobi_ilu0_apply_2048(one_chip, form):
     used = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert used < HBM_BYTES, used
+
+
+@pytest.mark.parametrize("pc_type", ["jacobi", "mg"])
+def test_solve_program_exports(topo, monkeypatch, pc_type):
+    """The stencil cells' single-RHS CG program exports for the chip
+    (utils/aot), its Pallas kernels included, when lowered for this
+    runtime as a jit lowers it: lowered for older runtimes too, Mosaic
+    recurses without end on the stencil kernels' 64-bit pad constant."""
+    import mpi_petsc4py_example_tpu as tps
+    from mpi_petsc4py_example_tpu.models import StencilPoisson3D
+    from mpi_petsc4py_example_tpu.parallel.mesh import DeviceComm
+    from mpi_petsc4py_example_tpu.solvers.krylov import build_ksp_program
+    from mpi_petsc4py_example_tpu.utils import aot
+
+    # described devices hold no arrays: every placement is its shape
+    monkeypatch.setattr(
+        DeviceComm, "_put", lambda self, arr, sharding: jax.ShapeDtypeStruct(
+            np.shape(arr), np.asarray(arr).dtype, sharding=sharding))
+    comm = DeviceComm(devices=[topo.devices[0]])
+    op = StencilPoisson3D(comm, 256, 256, 256, dtype=np.float32)
+    pc = tps.PC(comm)
+    pc.set_type(pc_type)
+    pc.set_up(op)
+    prog = build_ksp_program(comm, "cg", pc, op, zero_guess=True,
+                             true_res=True, donate=True)
+    v = jax.ShapeDtypeStruct((op.shape[0],), F32,
+                             sharding=comm.row_sharding)
+    args = (op.device_arrays(), pc.device_arrays(), v, v, np.float32(1e-6),
+            np.float32(0), np.float32(0), np.int32(10))
+    with aot._this_runtime():
+        exported = jax.export.export(prog.traced, platforms=["tpu"])(*args)
+    assert "tpu_custom_call" in exported.mlir_module()
