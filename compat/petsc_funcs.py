@@ -38,8 +38,6 @@ def createPETScMat(comm, shape, csr, backend=None):
     PETSc, _ = _modules(backend)
     A = PETSc.Mat().createAIJ(comm=comm, size=shape, csr=csr)
     A.assemble()
-    from mpi_petsc4py_example_tpu.utils.phases import stamp
-    stamp("mat_assembled")
     return A
 
 
@@ -52,6 +50,4 @@ def solveSLEPcEigenvalues(comm, A, backend=None):
     E.setProblemType(SLEPc.EPS.ProblemType.HEP)
     E.setFromOptions()
     E.solve()
-    from mpi_petsc4py_example_tpu.utils.phases import stamp
-    stamp("eps_solved")
     return E

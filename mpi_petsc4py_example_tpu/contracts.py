@@ -847,8 +847,7 @@ def _contracts():
           kind="persistent_serve",
           description="persistent serving over the stencil fused-dot "
                       "fast path: per-slot tolerances + donation + "
-                      "the gather-free halo channel in one program — "
-                      "the full cfg17 serving configuration",
+                      "the gather-free halo channel in one program",
           build=lambda comm: lower_persistent(
               comm, operator="stencil", stencil_fastpath=True),
           reduce_site_chain=(4, 2), min_donated_args=1,

@@ -61,7 +61,7 @@ def resident_zdepth(ny: int, nx: int, dtype, streams: int = 2,
                     nbuf: int | None = None, ncols: int = 1) -> int:
     """The deepest z-chunk the VMEM plan keeps resident for one
     ``(ny, nx)`` plane geometry at a given STORAGE dtype — the
-    resident-size probe of the mixed-precision bench (cfg11): bf16
+    resident-size probe of the mixed-precision plans: bf16
     storage halves the plane bytes, so the planned depth (and with it
     the largest grid that stays VMEM-resident) exactly doubles vs f32.
     Mirrors :func:`_pick_chunk`'s budget arithmetic without the

@@ -62,10 +62,7 @@ class DeviceComm:
                  devices=None, n_devices: int | None = None):
         if mesh is None:
             if devices is None:
-                from ..utils.phases import stamp
-                stamp("backend_init_begin")  # first jax.devices() initializes
-                devices = jax.devices()      # the backend
-                stamp("backend_init_end")
+                devices = jax.devices()
                 if n_devices is not None:
                     devices = devices[:n_devices]
             mesh = Mesh(np.asarray(devices), (axis,))

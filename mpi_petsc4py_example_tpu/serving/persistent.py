@@ -23,7 +23,7 @@ enqueued on the device stream *before* the host blocks fetching launch
 N's results (JAX async dispatch), so the device never idles between
 launches, and a burst of B batches costs ``ceil(B_requests / Q)``
 launches — the amortized ≪ 1 dispatch/request the
-``dispatch.programs`` counter proves under cfg17's sustained load.
+``dispatch.programs`` counter shows.
 Slot-count padding reuses the coalescer's pow2 discipline (a zero slot
 carries zero tolerances: its residual norm is 0, its target 0, it
 freezes at outer step 0). QoS ordering is preserved: slots fill in the

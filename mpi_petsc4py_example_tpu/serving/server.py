@@ -112,8 +112,7 @@ class ServedSolveResult(SolveResult):
     column); ``batch_width`` the number of REAL requests coalesced into
     the dispatched block (padding columns excluded); ``queue_wait`` the
     seconds the request waited between submission and dispatch (the
-    batching-window + backlog cost the latency percentiles in
-    benchmarks/run_all.py cfg9 report). ``wall_time`` is the whole
+    batching-window + backlog cost). ``wall_time`` is the whole
     block's wall — launches are shared, so per-request wall is not a
     meaningful quantity. The resilience trail (``attempts`` /
     ``recovery_events`` / SDC counters) is the BLOCK's: recovery acts on

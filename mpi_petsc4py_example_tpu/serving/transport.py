@@ -37,8 +37,7 @@ Two transports share the client/host classes:
   and the chaos drills. ``kill()`` models abrupt host loss: in-flight
   handler work completes host-side but no reply escapes.
 - :class:`SocketTransport` / :class:`SocketHostServer` — localhost TCP
-  with length-prefixed pickled frames, for real two-process drills and
-  the cfg18 benchmark's socket rows.
+  with length-prefixed pickled frames, for real two-process drills.
 
 Telemetry: each client call runs under an ``rpc.call`` span (method,
 host, attempts), re-sends count into ``rpc.retries``, collapsed

@@ -12,9 +12,7 @@ communication-cost). This module measures instead of guessing:
 
 * :func:`measure_psum_latency_us` — the chained-psum probe (one program
   running N dependent scalar psums): the per-reduce-site latency each
-  removed site buys back. Shared with
-  ``benchmarks/multichip_weak_scaling.py`` so the bench and the selector
-  price latency with ONE definition.
+  removed site buys back.
 * :func:`probe_psum_latency_us` — the same probe behind an on-disk cache
   keyed by ``host_machine_fingerprint()`` + mesh topology (the utils/aot
   discipline: atomic writes, silent fallback), so auto-select does not
@@ -204,10 +202,9 @@ def rank_reduction_plans(psum_us: float, apply_us: float,
 
 @dataclass
 class SelectionReport:
-    """What :func:`select_reduction_plan` decided and WHY — published
-    verbatim by cfg15 and the weak-scaling bench (the honesty contract:
-    on the CPU mesh the measured psum latency is ~µs and the report says
-    classic CG keeps winning)."""
+    """What :func:`select_reduction_plan` decided and WHY — kept on the
+    KSP as ``_reduction_report``, so a caller can read the measured
+    latencies the choice rests on."""
     ksp_type: str
     s: int
     psum_us: float

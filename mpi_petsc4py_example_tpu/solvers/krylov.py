@@ -1131,8 +1131,7 @@ def sstep_kernel(A, M, pdot, pnorm, b, x0, rtol, atol, maxit, *, s,
     The per-iteration reduction count drops to 1/s at the cost of
     ~2x the operator applies (the two-basis monomial CA-CG trade): the
     win is real exactly where per-reduction latency dominates per-apply
-    cost — the high-latency-interconnect regime the weak-scaling bench's
-    crossover model prices per method."""
+    cost — the high-latency-interconnect regime."""
     return _plans.sstep_cg_loop(
         b=b, x0=x0, rtol=rtol, atol=atol, maxit=maxit, dtol=dtol, s=s,
         greduce=greduce, A=A, M=M, pnorm=pnorm, monitor=monitor,

@@ -32,8 +32,7 @@ contract from synchrony to bounded staleness:
 Robustness is the headline. A per-device ``comm.delay`` timing fault
 (resilience/faults.py) simulates jittery or sticky-slow devices — the
 async tier absorbs them as staleness where every synchronous plan pays
-max-of-device latency per reduction (benchmarks cfg16 measures the
-crossover). A mid-solve ``device.lost`` degrades to ONE stale block:
+max-of-device latency per reduction. A mid-solve ``device.lost`` degrades to ONE stale block:
 the survivors keep iterating against the block's last exchanged version
 (frozen by ``StaleExchange.mark_lost``), and the failed block re-homes
 onto a survivor device FROM that version — per-block version counters

@@ -566,8 +566,8 @@ class RefinedKSP:
                     break
                 rnorm = r_new
         wall = time.perf_counter() - t0
-        # observability for the bench artifact (cfg6/cfg11): how many fp64
-        # outer corrections the inner-iteration total splits across
+        # how many fp64 outer corrections the inner-iteration total
+        # splits across
         self.refine_steps = it
         self.result = SolveResult(total_inner, float(rnorm), int(reason),
                                   wall)

@@ -28,10 +28,9 @@ Gating: the METRICS registry is always on (host dict updates, the same
 cost class as the globals it replaced). SPANS + flight ring + trace are
 armed by :func:`enable` / the ``-telemetry`` flag; disabled they are a
 shared no-op context manager — no allocation, no clock read, no device
-work, zero extra XLA programs (the cfg12 bench gates the armed overhead
-at <2% wall). :func:`enable` is the one call that touches jax: it
-registers the compile listener with ``jax.monitoring``, once per
-process; disabled, the listener returns at once.
+work, zero extra XLA programs. :func:`enable` is the one call that
+touches jax: it registers the compile listener with ``jax.monitoring``,
+once per process; disabled, the listener returns at once.
 
 Runtime flags (utils/options): ``-telemetry`` (arm spans+flight),
 ``-telemetry_flight_len N`` (ring length), ``-telemetry_dump <path>``

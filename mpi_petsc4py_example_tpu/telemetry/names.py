@@ -184,19 +184,7 @@ NAMES = {
     "elastic.mesh_shrinks": ("counter", "executed degraded-mesh rebuilds"),
     "elastic.mesh_regrows": ("counter", "executed mesh RE-GROW rebuilds "
                                         "(healed capacity re-adopted)"),
-    "kernel.model_bytes": ("counter", "useful roofline-model bytes by "
-                                      "kernel"),
-    "kernel.seconds": ("counter", "measured device seconds by kernel"),
-    "kernel.episodes": ("counter", "delta-method episodes by kernel"),
-    "collective.per_iter_seconds": ("counter", "summed per-iteration wall "
-                                               "by solver-loop label"),
-    "collective.episodes": ("counter", "collective-latency episodes by "
-                                       "label"),
     # ---- gauges ----
-    "collective.reduce_sites": ("gauge", "psum/all-reduce sites per "
-                                         "iteration by solver-loop label"),
-    "kernel.achieved_gbps": ("gauge", "achieved effective bandwidth by "
-                                      "kernel (model bytes / measured s)"),
     "solve.programs": ("gauge", "jit-compiled solver programs held "
                                 "(KSP + EPS caches)"),
     "serving.queue_depth": ("gauge", "pending requests at last submit"),

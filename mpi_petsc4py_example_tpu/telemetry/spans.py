@@ -246,8 +246,8 @@ def _finish_root(sp: Span, group: Span | None = None):
         # a span opened while armed may finish after disable() (e.g. a
         # detached serving.request resolved later on the dispatcher
         # thread) — drop it: the flight ring is only fed while
-        # telemetry is enabled (flight.py's contract), and the cfg12
-        # off-measurement must see a truly silent path
+        # telemetry is enabled (flight.py's contract), and a disarmed
+        # process must see a truly silent path
         return
     # lazy imports: flight/metrics import spans for enabled() — the
     # function-level import breaks the cycle at module-load time

@@ -1,8 +1,8 @@
 """Lowered-StableHLO inspection helpers for the collective-schedule gates.
 
-The collective-volume tests (tests/test_collective_volume.py) and the
-MULTICHIP weak-scaling bench both need to count the reduce sites INSIDE a
-solver loop's body — the per-iteration communication schedule the
+The collective-volume tests (tests/test_collective_volume.py), tpscheck's
+contracts and ``dryrun_multichip``'s schedule gate count the reduce sites
+INSIDE a solver loop's body — the per-iteration communication schedule the
 pipelined/guarded/classic reduction plans pin (1 / 2 / 3 sites). Whole-
 program ``all_reduce`` counts can't distinguish init/epilogue reductions
 from per-iteration ones, so this module walks the pretty-printed

@@ -227,41 +227,6 @@ def migration_count() -> int:
     return int(_REG.counter("fleet.migrations").total())
 
 
-def record_collective_latency(label: str, reduce_sites: float,
-                              per_iter_seconds: float):
-    """Record one measured collective-latency episode: a solver loop with
-    ``reduce_sites`` psum/all-reduce sites per iteration — FRACTIONAL for
-    the s-step plans, whose one Gram psum amortizes over s iterations
-    (1/s sites per iteration) — that ran at ``per_iter_seconds`` per
-    iteration on the mesh.
-
-    The MULTICHIP weak-scaling bench records each (solver, mesh, size)
-    point — classic CG's multi-site loop vs pipelined CG's 1-site loop
-    plus a direct chained-psum latency probe — so ``-log_view`` prints
-    the psum-latency itemization (seconds attributable to reduce sites
-    per iteration) instead of leaving it as benchmark prose."""
-    if per_iter_seconds <= 0:
-        return
-    _REG.counter("collective.per_iter_seconds").inc(
-        float(per_iter_seconds), label=str(label))
-    _REG.counter("collective.episodes").inc(label=str(label))
-    _REG.gauge("collective.reduce_sites").set(float(reduce_sites),
-                                              label=str(label))
-
-
-def collective_latency() -> dict[str, dict]:
-    """label -> {reduce_sites, per_iter_s (mean), episodes}."""
-    sums = _REG.counter("collective.per_iter_seconds").items()
-    eps = _REG.counter("collective.episodes").items()
-    sites = _REG.gauge("collective.reduce_sites").items()
-    out = {}
-    for k, n in eps.items():
-        out[k] = {"reduce_sites": float(sites.get(k, 0)),
-                  "episodes": int(n),
-                  "per_iter_s": (sums.get(k, 0.0) / n) if n else 0.0}
-    return out
-
-
 def record_sync(kind: str, count: int = 1):
     """Count a host<->device synchronization point (a blocking D2H fetch).
 
@@ -276,42 +241,6 @@ def record_sync(kind: str, count: int = 1):
 def sync_counts() -> dict[str, int]:
     return {k: int(v) for k, v in
             _REG.counter("sync.count").items().items()}
-
-
-def record_kernel_traffic(kernel: str, model_bytes: float, seconds: float):
-    """Record one measured kernel episode: ``model_bytes`` is the kernel's
-    USEFUL traffic (its roofline model — e.g. read u + write y for a
-    stencil apply), ``seconds`` the measured device time for those bytes.
-
-    The quotient is the kernel's ACHIEVED effective bandwidth — the number
-    a pass decomposition argues from. Recording it here makes
-    the plateau a first-class ``-log_view`` line (and a registry gauge,
-    ``kernel.achieved_gbps``) instead of benchmark prose: the bench
-    harnesses (bench.py, benchmarks/decompose_stencil.py) record each
-    delta-method measurement (round-6 VERDICT weak #4 observability).
-    """
-    if seconds <= 0 or model_bytes <= 0:
-        return
-    k = str(kernel)
-    _REG.counter("kernel.model_bytes").inc(float(model_bytes), label=k)
-    _REG.counter("kernel.seconds").inc(float(seconds), label=k)
-    _REG.counter("kernel.episodes").inc(label=k)
-    b = _REG.counter("kernel.model_bytes").value(k)
-    s = _REG.counter("kernel.seconds").value(k)
-    _REG.gauge("kernel.achieved_gbps").set(b / s / 1e9, label=k)
-
-
-def kernel_traffic() -> dict[str, dict]:
-    """kernel -> {model_bytes, seconds, episodes, achieved_gbps}."""
-    bts = _REG.counter("kernel.model_bytes").items()
-    secs = _REG.counter("kernel.seconds").items()
-    eps = _REG.counter("kernel.episodes").items()
-    out = {}
-    for k, n in eps.items():
-        b, s = bts.get(k, 0.0), secs.get(k, 0.0)
-        out[k] = {"model_bytes": b, "seconds": s, "episodes": int(n),
-                  "achieved_gbps": (b / s / 1e9) if s > 0 else 0.0}
-    return out
 
 
 def events() -> list[SolveEvent]:
@@ -337,12 +266,10 @@ def log_view(file=None):
     sdc = sdc_counts()
     serving = serving_stats()
     admission = admission_counts()
-    collectives = collective_latency()
-    kernels = kernel_traffic()
     per_iter = _REG.histogram("solve.per_iter_seconds")
-    if (not _EVENTS and not kernels and not syncs
+    if (not _EVENTS and not syncs
             and not any(sdc.values()) and not serving["batches"]
-            and not collectives and not _MESH_SHRINKS
+            and not _MESH_SHRINKS
             and not _MESH_REGROWS and not migration_count()
             and not any(admission.values())):
         print("log_view: no solve events recorded", file=file)
@@ -400,21 +327,6 @@ def log_view(file=None):
     if migration_count():
         print(f"fleet: {migration_count()} session migration(s)",
               file=file)
-    if collectives:
-        print("collective latency itemization (reduce sites x per-iter "
-              "wall):", file=file)
-        for k, info in sorted(collectives.items()):
-            print(f"  {k:36s} {info['reduce_sites']:4.2f} site(s) "
-                  f"{info['per_iter_s'] * 1e6:10.1f} us/iter "
-                  f"({info['episodes']} episode(s))", file=file)
-    if kernels:
-        print("kernel traffic (model bytes / measured time = achieved "
-              "GB/s):", file=file)
-        for k, info in sorted(kernels.items()):
-            print(f"  {k:30s} {info['model_bytes'] / 1e9:10.3f} GB "
-                  f"{info['seconds']:9.4f} s "
-                  f"{info['achieved_gbps']:8.1f} GB/s "
-                  f"({info['episodes']} episode(s))", file=file)
     dispatches = dispatch_counts()
     if dispatches:
         # the megasolve measurement row: launches by program kind — a
@@ -441,8 +353,8 @@ def log_view(file=None):
               f"launch(es), mean {s['mean']:.2f}, p50 {s['p50']:.1f}, "
               f"p99 {s['p99']:.1f}): {cells}", file=file)
     if per_iter.count:
-        # the fixed-bucket per-iteration latency histogram (cfg12's
-        # -log_view row): only occupied buckets, cumulative-free
+        # the fixed-bucket per-iteration latency histogram: only
+        # occupied buckets, cumulative-free
         s = per_iter.summary((50, 99))
         occupied = [(b, c) for b, c in
                     zip(list(per_iter.buckets) + [float("inf")],

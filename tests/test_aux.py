@@ -418,39 +418,3 @@ class TestGetters:
         out = capsys.readouterr().out
         assert "KSP Object: type=cg" in out
         assert "norm type:" in out and "divtol=" in out
-
-
-class TestPhaseStamps:
-    def test_concurrent_stamps_keep_valid_json(self, tmp_path, monkeypatch):
-        """utils/phases.py: tpurun's virtual ranks stamp from threads; the
-        lock + atomic replace must keep the log parseable at all times and
-        lose no stamps (the cfg2 artifact itemization depends on it)."""
-        import json
-        import threading
-
-        from mpi_petsc4py_example_tpu.utils import phases
-        log = tmp_path / "phases.json"
-        monkeypatch.setenv("TPU_SOLVE_PHASE_LOG", str(log))
-        monkeypatch.setattr(phases, "_STAMPS", [])
-
-        def worker(rank):
-            for k in range(25):
-                phases.stamp(f"r{rank}_k{k}")
-
-        threads = [threading.Thread(target=worker, args=(r,))
-                   for r in range(4)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        data = json.load(open(log))          # must parse
-        assert len(data) == 100              # no stamp lost
-        names = {n for n, _ in data}
-        assert names == {f"r{r}_k{k}" for r in range(4) for k in range(25)}
-
-    def test_stamp_noop_without_env(self, monkeypatch):
-        from mpi_petsc4py_example_tpu.utils import phases
-        monkeypatch.delenv("TPU_SOLVE_PHASE_LOG", raising=False)
-        before = list(phases._STAMPS)
-        phases.stamp("ignored")
-        assert phases._STAMPS == before

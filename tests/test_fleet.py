@@ -641,6 +641,14 @@ class TestRegrow:
         victim = comm8.device_ids[-1]
         rt = SolveRouter(1, comm8, window=0.003, max_k=4,
                          retry_policy=_fast_policy())
+
+        def replica():
+            return list(rt.stats()["per_replica"].values())[0]
+
+        def rel(j, r):
+            return (np.linalg.norm(B[:, j] - A @ r.x)
+                    / np.linalg.norm(B[:, j]))
+
         try:
             rt.register_operator("p", A, pc_type="jacobi", rtol=RTOL)
             # at=1: the FIRST dispatched block hits the loss whatever
@@ -651,13 +659,19 @@ class TestRegrow:
                 futs = [rt.submit("p", B[:, j]) for j in range(4)]
                 res = [f.result(600) for f in futs]
             assert all(r.converged for r in res)
+            # fp64 parity across the shrink, which resumed past 0
+            assert all(rel(j, r) <= RTOL * 1.05 for j, r in enumerate(res))
             assert rt.stats()["mesh_shrinks"] == 1
+            assert replica()["mesh_shrinks"][-1]["resumed_iteration"] > 0
+            assert replica()["devices"] < comm8.size
             assert rt.heal_check() == 0        # nothing healed yet
             _faults.heal()
             assert rt.heal_check() == 1        # the replica re-grew
             assert rt.stats()["mesh_regrows"] == 1
+            assert replica()["devices"] == comm8.size
             r = rt.solve("p", B[:, 0], timeout=300)
             assert r.converged
+            assert rel(0, r) <= RTOL * 1.05
         finally:
             rt.shutdown(wait=False)
             _faults.heal()
@@ -748,21 +762,44 @@ class TestTransport:
         A, Xt, B = _problem(k=1)
         b = B[:, 0]
         mgr = self._fleet(2)
+
+        def rel(r):
+            return np.linalg.norm(b - A @ r.x) / np.linalg.norm(b)
+
         try:
             mgr.register_operator("a", A, pc_type="jacobi", rtol=1e-10)
             res = mgr.submit("a", b).result(timeout=120)
             np.testing.assert_allclose(res.x, Xt[:, 0], atol=1e-6)
+            assert rel(res) <= 1e-10 * 1.05
             mgr.lease_step()                  # pull the warm checkpoint
             owner = mgr.router.owner("a")
             mgr.kill_host(owner)
             res2 = mgr.submit("a", b).result(timeout=120)
             np.testing.assert_allclose(res2.x, Xt[:, 0], atol=1e-6)
+            assert rel(res2) <= 1e-10 * 1.05
             assert mgr.router.owner("a") != owner
             assert mgr.failovers and mgr.failovers[0].sessions == ("a",)
             assert mgr.failovers[0].resumed_iteration > 0
         finally:
             mgr.shutdown(wait=False)
             _faults.heal()
+
+    @pytest.mark.parametrize("transport", ["loopback", "socket"])
+    def test_round_trip_parity(self, transport):
+        """A burst served through a two-host fleet over each transport:
+        every answer meets rtol 1e-10 in the fp64 TRUE residual."""
+        A, _, B = _problem(k=3, seed=9)
+        mgr = self._fleet(2, transport=transport)
+        try:
+            mgr.register_operator("a", A, pc_type="jacobi", rtol=1e-10)
+            for j in range(B.shape[1]):
+                r = mgr.solve("a", B[:, j], timeout=120)
+                assert r.converged, (j, r)
+                rel = (np.linalg.norm(B[:, j] - A @ r.x)
+                       / np.linalg.norm(B[:, j]))
+                assert rel <= 1e-10 * 1.05, (j, rel)
+        finally:
+            mgr.shutdown(wait=False)
 
     def test_suspected_host_gets_degraded_deadline(self):
         """The lease ladder's first rung: enough missed pings mark the

@@ -1,8 +1,8 @@
 """Unit tests for utils/hlo.py — the while-body reduce-site parser.
 
 The parser gates the collective-volume tests (the 3/2/1 reduce-site
-schedules of classic/guarded/pipelined CG) and the MULTICHIP bench's
-one-reduce-site go/no-go check, but until round 9 it had no direct unit
+schedules of classic/guarded/pipelined CG) and the multi-chip dry-run's
+one-reduce-site check, but until round 9 it had no direct unit
 tests — a regression in the brace-matching walk would have surfaced as
 an opaque schedule-gate failure three layers up.  These tests pin the
 edge cases on hand-built StableHLO-shaped text (the textual contract
@@ -10,8 +10,8 @@ the module documents): programs with zero while-loops, nested while
 bodies, multiple reduce dtypes in ONE stacked variadic all_reduce, and
 the conditional-region exclusion.
 
-A final test runs the parser against a REAL lowered program so the
-textual fixtures cannot drift from what jax actually prints.
+Tests against REAL lowered programs keep the textual fixtures from
+drifting from what jax actually prints, and pin the stencil schedules.
 """
 
 import textwrap
@@ -326,6 +326,18 @@ def test_parser_against_real_lowered_program(nsites):
     fn = jax.jit(comm.shard_map(local_fn, (P(axis),), P(axis)))
     text = fn.lower(jnp.ones(8)).as_text()
     assert solver_loop_reduce_sites(text) == nsites
+
+
+@pytest.mark.parametrize("ksp_type,sstep_s", [("pipecg", None),
+                                               ("sstep", 2), ("sstep", 4),
+                                               ("sstep", 8)])
+def test_stencil_schedule_is_one_reduce_site(comm8, ksp_type, sstep_s):
+    """The schedule gate on the sharded stencil operator: pipelined CG's
+    loop body and each s-step block lower to ONE reduce site."""
+    from mpi_petsc4py_example_tpu.contracts import lower_ksp
+    text = lower_ksp(comm8, ksp_type=ksp_type, pc_type="jacobi",
+                     operator="stencil", sstep_s=sstep_s)
+    assert solver_loop_reduce_sites(text) == 1
 
 
 # --------------------------------------- doubly-nested chains (megasolve)

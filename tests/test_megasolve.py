@@ -433,8 +433,8 @@ class TestFusedServing:
             srv.register_operator("op", M, pc_type="jacobi", rtol=1e-9,
                                   megasolve=True, warm_widths=(4,))
             before = dispatch_counts()
-            futs = [srv.submit("op", rng.standard_normal(512))
-                    for _ in range(3)]
+            rhs = [rng.standard_normal(512) for _ in range(3)]
+            futs = [srv.submit("op", b) for b in rhs]
             srv.start()
             results = [f.result(120) for f in futs]
             assert srv.drain(120)
@@ -445,6 +445,9 @@ class TestFusedServing:
         assert launches == stats["batches"] >= 1
         for i, r in enumerate(results):
             assert r.converged, r
+            # each request meets its rtol in the fp64 TRUE residual
+            rel = np.linalg.norm(rhs[i] - A @ r.x) / np.linalg.norm(rhs[i])
+            assert rel <= 1e-9 * 1.05, (i, rel)
         # and no unfused block launches leaked onto the hot path
         assert int(after.get("ksp_many", 0)
                    - before.get("ksp_many", 0)) == 0

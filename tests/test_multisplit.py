@@ -11,7 +11,7 @@ iteration 0 (version counters stay monotonic across the loss); and the
 serving tier's ``multisplit`` schedule class routes per-request solves
 through the async tier with the QoS-urgent staleness tightening.
 tools/chaos_smoke.py ``--multisplit`` drills the same properties under
-heavier fault schedules; benchmarks cfg16 measures the jitter crossover.
+heavier fault schedules.
 """
 
 import io
@@ -289,6 +289,24 @@ class TestDegradation:
         assert res.resyncs > 0      # the sticky straggler tripped the bound
         assert res.max_stale_seen <= 3     # bound+1: detection then resync
         assert np.linalg.norm(b - A @ res.x) <= 1e-9 * np.linalg.norm(b)
+
+    def test_jitter_on_every_device_keeps_strict_parity(self, comm8):
+        """Seeded delays on every block worker at once: staleness
+        absorbs them, and the answer still meets rtol 1e-10 in the fp64
+        true residual."""
+        A = tridiag(256)
+        _, b = manufactured(A, seed=16)
+        ms = MultisplitSolver(comm8, nblocks=4, rtol=1e-10,
+                              inner_rtol=1e-4)
+        ms.set_operator(A)
+        try:
+            with tps.inject_faults(
+                    "comm.delay=delay:times=*:mean=0.002:seed=16"):
+                res = ms.solve(b)
+        finally:
+            faults.heal()
+        assert res.converged, res
+        assert np.linalg.norm(b - A @ res.x) <= 1e-10 * np.linalg.norm(b)
 
     def test_device_lost_degrades_and_never_restarts(self, comm8):
         A = tridiag(256)

@@ -88,6 +88,23 @@ class TestPipecgParity:
         rel = np.linalg.norm(xp - xc) / np.linalg.norm(xc)
         assert rel <= 1e-10, rel
 
+    def test_fixed_iteration_contract(self, comm8):
+        """-ksp_norm_type none on the stencil: EXACTLY max_it
+        iterations, reported as CONVERGED_ITS."""
+        op, A = _operator("stencil", comm8)
+        b = np.asarray(A @ np.ones(A.shape[0]))
+        ksp = tps.KSP().create(comm8)
+        ksp.set_operators(op)
+        ksp.set_type("pipecg")
+        ksp.get_pc().set_type("jacobi")
+        ksp.set_norm_type("none")
+        ksp.set_tolerances(max_it=13)
+        x, bv = op.get_vecs()
+        bv.set_global(b)
+        res = ksp.solve(bv, x)
+        assert res.iterations == 13, res
+        assert res.reason == tps.ConvergedReason.CONVERGED_ITS
+
     def test_pc_none_and_bjacobi(self, comm8):
         op, A = _operator("ell", comm8)
         x_true = np.random.default_rng(5).random(A.shape[0])
@@ -297,13 +314,3 @@ class TestPipecgServing:
                     / np.linalg.norm(B[:, j]))
             assert rres <= 1e-9 * 1.1, (j, rres)
         assert max(r.batch_width for r in results) >= 2
-
-
-class TestWeakScalingBenchSmoke:
-    @pytest.mark.slow
-    def test_bench_runs_and_gates(self, tmp_path):
-        from benchmarks import multichip_weak_scaling as mws
-        res = mws.run(devices=(2,), sizes=(16,), iters=10, repeats=1,
-                      out=str(tmp_path / "mws.json"), smoke=True)
-        assert res["one_reduce_site_gate"] == 1
-        assert res["points"] and res["points"][0]["parity_rel_diff"] <= 1e-6

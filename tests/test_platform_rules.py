@@ -1,7 +1,6 @@
 """Rules that decide where the program runs and what it assumes of the
-device: the compile-cache placement, the per-device tables that refuse an
-unknown chip, the native build key, and the capability gates as the chip
-showed them (chip_smoke.py, PR 21)."""
+device: the compile-cache placement, the native build key, and the
+capability gates as the chip showed them (chip_smoke.py)."""
 
 import os
 import subprocess
@@ -45,24 +44,6 @@ class TestCompileCachePlacement:
         monkeypatch.delenv("TPU_SOLVE_AOT_DIR", raising=False)
         assert aot.cache_dir() == os.path.join(REPO, ".tpu_solve_cache",
                                                "aot")
-
-
-@pytest.fixture(scope="module")
-def bench():
-    sys.path.insert(0, REPO)
-    import bench
-    sys.path.remove(REPO)
-    return bench
-
-
-class TestDeviceTables:
-    def test_hbm_peak_v5e(self, bench):
-        assert bench.hbm_peak_gbps("TPU v5 lite") == 819.0
-
-    @pytest.mark.parametrize("kind", ["TPU v4", "TPU v6 lite", "cpu"])
-    def test_hbm_peak_unknown_raises(self, bench, kind):
-        with pytest.raises(ValueError, match="no HBM peak known"):
-            bench.hbm_peak_gbps(kind)
 
 
 class TestNativeBuildKey:

@@ -108,6 +108,20 @@ class TestSstepParity:
         rel = np.linalg.norm(xs - xc) / np.linalg.norm(xc)
         assert rel <= 1e-7, (s, rel)
 
+    def test_stencil_parity_vs_cg(self, comm8):
+        """On the sharded stencil every block size converges to classic
+        CG's answer (rtol 1e-8, iterates within 1e-6)."""
+        op, A = _operator("stencil", comm8)
+        b = np.asarray(A @ np.random.default_rng(7).standard_normal(
+            A.shape[0]))
+        xc, rc = _solve(comm8, op, b, "cg", rtol=1e-8)
+        assert rc.converged, rc
+        for s in (2, 4, 8):
+            xs, rs = _solve(comm8, op, b, "sstep", rtol=1e-8, sstep_s=s)
+            assert rs.converged, (s, rs)
+            rel = np.linalg.norm(xs - xc) / np.linalg.norm(xc)
+            assert rel <= 1e-6, (s, rel)
+
     def test_pc_none_and_bjacobi(self, comm8):
         op, A = _operator("ell", comm8)
         x_true = np.random.default_rng(7).random(A.shape[0])
@@ -120,8 +134,7 @@ class TestSstepParity:
 
     def test_fixed_iteration_contract(self, comm8):
         """-ksp_norm_type none: EXACTLY max_it iterations whatever the
-        blocking (partial blocks freeze by per-step masking) — the
-        weak-scaling bench's timing-mode requirement."""
+        blocking (partial blocks freeze by per-step masking)."""
         op, A = _operator("stencil", comm8)
         b = np.asarray(A @ np.ones(A.shape[0]))
         for s, iters in ((2, 21), (4, 10), (8, 40)):

@@ -843,8 +843,8 @@ def drill_multisplit_jitter() -> list[str]:
     per step. The bounded-staleness supervisor must absorb it — resyncs
     fire, observed staleness stays within the bound — and the solve must
     still land at strict fp64 parity. Every synchronous plan pays this
-    straggler at every reduction; cfg16 measures that crossover, this
-    drill proves the tolerance machinery."""
+    straggler at every reduction; this drill proves the tolerance
+    machinery."""
     import mpi_petsc4py_example_tpu as tps
     from mpi_petsc4py_example_tpu.resilience import faults as _faults
     from mpi_petsc4py_example_tpu.solvers.multisplit import MultisplitSolver

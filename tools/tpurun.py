@@ -7,14 +7,13 @@ Usage::
 
 Spawns N threads, each executing ``driver.py`` as ``__main__`` with a
 thread-local MPI rank (compat/mpi4py). :func:`main` takes an argument list,
-so a process that already holds the chip (chip_smoke.py,
-benchmarks/run_all.py) runs a driver in-process rather than in a child
-that would need the same chip. Point-to-point sends/recvs and
-collectives rendezvous in-process; device work (assembly, KSP/EPS solves)
-executes once on the rank-0 thread over the full device mesh. This is the
-TPU analog of the reference's oversubscribed ``mpirun -n N python test.py``
-testing idiom (SURVEY.md §4) — the way to exercise multi-rank driver logic
-without a cluster or MPI.
+so a process that already holds the chip (chip_smoke.py) runs a driver
+in-process rather than in a child that would need the same chip.
+Point-to-point sends/recvs and collectives rendezvous in-process; device
+work (assembly, KSP/EPS solves) executes once on the rank-0 thread over the
+full device mesh. This is the TPU analog of the reference's oversubscribed
+``mpirun -n N python test.py`` testing idiom (SURVEY.md §4) — the way to
+exercise multi-rank driver logic without a cluster or MPI.
 """
 
 from __future__ import annotations
@@ -51,8 +50,6 @@ def _run(argv):
     for p in (repo, compat):
         if p not in sys.path:
             sys.path.insert(0, p)
-    from mpi_petsc4py_example_tpu.utils.phases import stamp
-    stamp("tpurun_main")         # interpreter + site imports are behind us
     # like ``python script.py`` (and mpirun): the script's own directory leads
     # sys.path, so a driver's sibling modules (e.g. the reference repo's
     # petsc_funcs.py, /root/reference/test2.py:4) shadow the compat copies
@@ -67,7 +64,6 @@ def _run(argv):
 
     with open(opts.script) as f:
         code = compile(f.read(), opts.script, "exec")
-    stamp("driver_exec")
 
     nprocs = opts.np
     errors: list = []
