@@ -55,6 +55,7 @@ of a row-by-row numpy ILU(0).
 
 from __future__ import annotations
 
+import functools
 import time
 
 import jax
@@ -63,6 +64,8 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..ops.double_f32 import dd_add, dd_mul, from_pair, to_pair
 
 # rows a block aims at: 65,536 = one MPI rank's share of a 2048^2 grid
 # on a 64-rank node, PETSc's one block per rank
@@ -142,12 +145,11 @@ def factor(dia: np.ndarray, m: int, lines: int,
 
 def group_size(stack_shape, ndev: int) -> int:
     """Blocks per kernel instance of :func:`apply_pallas`: the largest of
-    8, 4, 2, 1 dividing a device's block count whose in- and
-    output blocks, double-buffered, fit :data:`KERNEL_VMEM`; 0 when not
-    even one block does."""
+    8, 4, 2, 1 dividing a device's block count that fits
+    (:func:`kernel_fits`); 0 when not even one block does."""
     nb, _, lines, m = stack_shape
     for g in (8, 4, 2, 1):
-        if (nb // ndev) % g == 0 and _kernel_vmem(lines, g, m) <= KERNEL_VMEM:
+        if (nb // ndev) % g == 0 and kernel_fits(lines, g, m):
             return g
     return 0
 
@@ -314,9 +316,8 @@ def apply_xla(coef, r):
 #
 # Mosaic holds no fp64, and XLA:TPU's emulated fp64 runs each doubling step
 # of apply_xla as its own fusion through HBM. The kernel keeps a group of
-# blocks' lines in VMEM and carries every value as an unevaluated sum
-# hi + lo of two float32 (Dekker's double-length arithmetic, ~48 bits of
-# mantissa, the precision of XLA:TPU's own fp64 emulation).
+# blocks' lines in VMEM and carries every value as a double-f32 pair
+# (ops/double_f32.py).
 
 # the kernel's coefficient planes, each a (hi, lo) pair: (row of the fp64
 # stack, sign). Negated so that both sweeps only add products:
@@ -325,46 +326,36 @@ def apply_xla(coef, r):
 PALLAS_ROWS = ((LW, -1.0), (LS, -1.0), (DINV, 1.0), (UE, -1.0), (UN, -1.0))
 _AW, _NS, _PD, _AE, _NN = range(5)
 # VMEM for the kernel's double-buffered blocks (of the 128 MiB of a v5e
-# core); the limit asked of Mosaic adds room for its temporaries
-KERNEL_VMEM = 64 << 20
-_VMEM_LIMIT = 96 << 20
-_SPLIT = 4097.0     # 2**12 + 1: splits a float32 into two 12-bit halves
+# core; fp64 BCGS's p-update call at 2048^2 in groups of 8 blocks of 32
+# lines asks all of it); the limit asked of Mosaic adds room for its
+# temporaries
+KERNEL_VMEM = 80 << 20
+VMEM_LIMIT = 96 << 20
 
 
-def _kernel_vmem(lines: int, g: int, m: int) -> int:
-    """Bytes of :func:`apply_pallas`'s blocks: 10 coefficient and 2 input
-    planes in, 2 out, two buffers each."""
-    return 2 * (2 * len(PALLAS_ROWS) + 4) * lines * g * m * 4
+def smem_spec(scalars):
+    """The BlockSpec of a float32 array of scalars read whole from SMEM
+    (its block index int32, where x64 would make a literal 0 an int64
+    that Mosaic rejects)."""
+    return pl.BlockSpec(scalars.shape, lambda i: (i * 0,),
+                        memory_space=pltpu.SMEM)
 
 
-def _two_sum(a, b):
-    s = a + b
-    v = s - a
-    return s, (a - (s - v)) + (b - v)
+def read_scalars(sc_ref, n: int, shape) -> tuple:
+    """The ``n`` (hi, lo) scalar pairs of ``sc_ref`` (SMEM), each half
+    broadcast to ``shape``: Mosaic bitcasts (``split``) vectors only."""
+    return tuple(tuple(jnp.broadcast_to(sc_ref[2 * k + h], shape)
+                       for h in range(2)) for k in range(n))
 
 
-def _fast_two_sum(a, b):
-    s = a + b
-    return s, b - (s - a)
-
-
-def _split(a):
-    t = _SPLIT * a
-    hi = t - (t - a)
-    return hi, a - hi
-
-
-def _dd_add(x, y):
-    s, e = _two_sum(x[0], y[0])
-    return _fast_two_sum(s, e + (x[1] + y[1]))
-
-
-def _dd_mul(x, y):
-    p = x[0] * y[0]
-    ah, al = _split(x[0])
-    bh, bl = _split(y[0])
-    e = ((ah * bh - p) + ah * bl + al * bh) + al * bl
-    return _fast_two_sum(p, e + (x[0] * y[1] + x[1] * y[0]))
+def kernel_fits(lines: int, g: int, m: int, io_planes: int = 4) -> bool:
+    """Whether :func:`apply_pallas_dd`'s blocks for groups of ``g`` blocks
+    of ``lines`` lines of ``m``, two buffers each, fit
+    :data:`KERNEL_VMEM`: 10 coefficient planes and ``io_planes`` float32
+    planes in and out (4 for ``z = M^-1 r``; 10 where it reads r, p, v
+    and writes p and ``M^-1 p``)."""
+    planes = 2 * len(PALLAS_ROWS) + io_planes
+    return 2 * planes * lines * g * m * 4 <= KERNEL_VMEM
 
 
 def _dd_recurrence(a, c, reverse: bool):
@@ -382,34 +373,49 @@ def _dd_recurrence(a, c, reverse: bool):
                            len(shape) - 1)
             return jnp.where(keep, v, 0.0)
 
-        c = _dd_add(c, _dd_mul(a, (shift(c[0]), shift(c[1]))))
+        c = dd_add(c, dd_mul(a, (shift(c[0]), shift(c[1]))))
         if 2 * s < m:
-            a = _dd_mul(a, (shift(a[0]), shift(a[1])))
+            a = dd_mul(a, (shift(a[0]), shift(a[1])))
         s *= 2
     return c
 
 
-def _ilu_kernel(coef_ref, rh_ref, rl_ref, zh_ref, zl_ref):
+def _ilu_kernel(n_sc, n_in, combine, *refs):
     """One group of blocks, lines-major ``(lines, g, m)``: the forward
     sweep writes y into the outputs line by line, the backward sweep
-    reads each line's y back and overwrites it with x."""
-    lines = rh_ref.shape[0]
+    reads each line's y back and overwrites it with x. With ``combine``
+    the right-hand side of each line is ``combine(scalars, *lines)`` of
+    the ``n_in`` input pairs and ``n_sc`` scalar pairs (from SMEM), and is
+    written out too; without, it is the one input pair."""
+    sc_ref, refs = (refs[0], refs[1:]) if n_sc else (None, refs)
+    coef_ref, refs = refs[0], refs[1:]
+    ins = [refs[2 * k:2 * k + 2] for k in range(n_in)]
+    outs = refs[2 * n_in:]
+    zh_ref, zl_ref = outs[-2:]
+    lines = zh_ref.shape[0]
+    sc = read_scalars(sc_ref, n_sc, zh_ref.shape[1:])
 
     def co(k, j):
         return coef_ref[2 * k, j], coef_ref[2 * k + 1, j]
 
-    zero = jnp.zeros(rh_ref.shape[1:], jnp.float32)
+    zero = jnp.zeros(zh_ref.shape[1:], jnp.float32)
 
     def forward(j, below):
-        c = _dd_add((rh_ref[j], rl_ref[j]), _dd_mul(co(_NS, j), below))
+        rhs = [(h[j], lo[j]) for h, lo in ins]
+        if combine is None:
+            (rhs,) = rhs
+        else:
+            rhs = combine(sc, *rhs)
+            outs[0][j], outs[1][j] = rhs
+        c = dd_add(rhs, dd_mul(co(_NS, j), below))
         y = _dd_recurrence(co(_AW, j), c, reverse=False)
         zh_ref[j], zl_ref[j] = y
         return y
 
     def backward(t, above):
         j = jnp.int32(lines - 1) - t
-        c = _dd_add(_dd_mul(co(_PD, j), (zh_ref[j], zl_ref[j])),
-                    _dd_mul(co(_NN, j), above))
+        c = dd_add(dd_mul(co(_PD, j), (zh_ref[j], zl_ref[j])),
+                   dd_mul(co(_NN, j), above))
         x = _dd_recurrence(co(_AE, j), c, reverse=True)
         zh_ref[j], zl_ref[j] = x
         return x
@@ -421,31 +427,62 @@ def _ilu_kernel(coef_ref, rh_ref, rl_ref, zh_ref, zl_ref):
     lax.fori_loop(start, stop, backward, (zero, zero))
 
 
-def apply_pallas(coef, r, interpret: bool = False):
-    """:func:`apply` as one Pallas kernel over groups of ``g`` blocks:
-    ``coef`` is :func:`pallas_stack`'s ``(groups, 10, lines, g, m)``,
-    ``r`` fp64. XLA splits r into (hi, lo) float32 lines-major and joins
-    the result back."""
+def lines_major(v, g: int, lines: int, m: int):
+    """A vector of this device's rows in the kernel's layout: each group
+    of ``g`` blocks ``(lines, g, m)``, line ``j`` of its blocks together."""
+    return v.reshape(-1, g, lines, m).transpose(0, 2, 1, 3)
+
+
+def natural(V):
+    """:func:`lines_major`'s inverse: the rows in their own order."""
+    return V.transpose(0, 2, 1, 3).reshape(-1)
+
+
+def apply_pallas_dd(coef, pairs, scalars=None, combine=None, reuse=None,
+                    interpret: bool = False):
+    """The kernel on (hi, lo) float32 pairs held in its layout
+    (:func:`lines_major`, ``(groups, lines, g, m)`` each): ``z`` for the
+    one pair of ``pairs``, or, with ``combine``, ``[c, z]`` for the
+    right-hand side ``c = combine(scalars, *pairs)`` formed line by line
+    in the forward sweep (``scalars``: a float32 array of (hi, lo) scalar
+    pairs, read from SMEM), written over ``pairs[reuse]`` where given
+    (a line is read before it is written)."""
     ng, planes, lines, g, m = coef.shape
-    R = r.reshape(ng, g, lines, m).transpose(0, 2, 1, 3)
-    rh = R.astype(jnp.float32)
-    rl = (R - rh.astype(r.dtype)).astype(jnp.float32)
     # block indices stay int32 (i * 0), where x64 would make a literal 0
     # an int64 that Mosaic rejects
     vec = pl.BlockSpec((None, lines, g, m),
                        lambda i: (i, i * 0, i * 0, i * 0))
-    zh, zl = pl.pallas_call(
-        _ilu_kernel,
+    n_sc = 0 if scalars is None else scalars.shape[0] // 2
+    sc_specs = [smem_spec(scalars)] if n_sc else []
+    n_out = 2 if combine is None else 4
+    first = len(sc_specs) + 1 + 2 * (reuse or 0)
+    outs = pl.pallas_call(
+        functools.partial(_ilu_kernel, n_sc, len(pairs), combine),
         grid=(ng,),
-        in_specs=[pl.BlockSpec((None, planes, lines, g, m),
-                               lambda i: (i,) + (i * 0,) * 4),
-                  vec, vec],
-        out_specs=[vec, vec],
-        out_shape=[jax.ShapeDtypeStruct(rh.shape, jnp.float32)] * 2,
+        in_specs=sc_specs + [pl.BlockSpec((None, planes, lines, g, m),
+                                          lambda i: (i,) + (i * 0,) * 4)]
+        + [vec] * (2 * len(pairs)),
+        out_specs=[vec] * n_out,
+        out_shape=[jax.ShapeDtypeStruct((ng, lines, g, m),
+                                        jnp.float32)] * n_out,
+        input_output_aliases=({} if reuse is None
+                              else {first: 0, first + 1: 1}),
         name="bjacobi_ilu0_pallas",
         compiler_params=None if interpret else pltpu.CompilerParams(
-            vmem_limit_bytes=_VMEM_LIMIT),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(coef, rh, rl)
-    z = zh.astype(r.dtype) + zl.astype(r.dtype)
-    return z.transpose(0, 2, 1, 3).reshape(-1)
+    )(*([scalars] if n_sc else []), coef, *(a for p in pairs for a in p))
+    outs = [tuple(outs[k:k + 2]) for k in range(0, n_out, 2)]
+    return outs[0] if combine is None else outs
+
+
+def apply_pallas(coef, r, interpret: bool = False):
+    """:func:`apply` as one Pallas kernel over groups of ``g`` blocks:
+    ``coef`` is :func:`pallas_stack`'s ``(groups, 10, lines, g, m)``,
+    ``r`` fp64. XLA splits r into (hi, lo) float32 lines-major and joins
+    the result back (:func:`apply_pallas_dd` takes the pairs as they
+    are)."""
+    _, _, lines, g, m = coef.shape
+    z = apply_pallas_dd(coef, [to_pair(lines_major(r, g, lines, m))],
+                        interpret=interpret)
+    return natural(from_pair(*z, r.dtype))

@@ -2274,11 +2274,22 @@ def build_ksp_program(comm: DeviceComm, ksp_type: str, pc, operator,
     donate_k = bool(donate) and donation_supported()
     trace_nonce = _faults.trace_key()
     aot_on = aot.aot_enabled()
+    # fp64 BCGS on one TPU chip with bjacobi's ILU(0) kernel runs as
+    # double-f32 Pallas sweeps (solvers/bcgs_dd.py): its group layout,
+    # from the PC's stack shape, which the keys below do not otherwise
+    # hold. The projection, the fault points and the live monitor's
+    # callbacks live in the XLA loop. Imported here, where that PC has
+    # loaded Pallas already: no other build pays for the import
+    dd = None
+    if (pc.kind == "bjacobi_ilu0" and not nullspace_dim and not live_k
+            and trace_nonce is None):
+        from . import bcgs_dd
+        dd = bcgs_dd.layout(comm, ksp_type, pc, operator)
     key = (comm.mesh, axis, ksp_type, pc.program_key(), n, prec.key(),
            restart_k, monitored, zero_guess, operator.program_key(),
            nullspace_dim, aug_k, ell_k, unroll_k, natural_k, cap_k, live_k,
            true_res_k, abft_k, abft_pc_k, bool(rr), donate_k, sstep_k,
-           trace_nonce, aot_on)
+           trace_nonce, aot_on, dd)
     cached = _PROGRAM_CACHE.get(key)
     if cached is not None:
         return cached
@@ -2407,6 +2418,12 @@ def build_ksp_program(comm: DeviceComm, ksp_type: str, pc, operator,
                                         for q in parts]), axis)
 
             eps = _abft.checksum_tolerance_dtype(dtype)
+
+            if dd is not None:
+                return bcgs_dd.solve(
+                    *dd, op_arrays, pc_arrays, b, x0, rtol, atol, maxit,
+                    offsets=operator.dia_offsets, zero_guess=zero_guess,
+                    interpret=comm.platform != "tpu", **kw)
 
             if stencil_cg:
                 idt = rdt if mixed else b.dtype
@@ -2685,6 +2702,8 @@ def build_ksp_program(comm: DeviceComm, ksp_type: str, pc, operator,
                         key[1:] + (aot.operand_shapes(
                             operator.device_arrays(), pc.device_arrays()),),
                         prog, donate_argnums=dn)
+    # what runs the iteration, for the ksp.setup span
+    prog.loop = "xla" if dd is None else "dd_pallas"
     _PROGRAM_CACHE[key] = prog
     return prog
 

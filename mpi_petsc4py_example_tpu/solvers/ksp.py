@@ -715,7 +715,7 @@ class KSP:
                 abft_pc=abft_pc_on,
                 rr=guard and self._effective_replacement() > 0,
                 donate=True, sstep_s=self.sstep_s)
-            setup_span.set_attr("aot", _aot.status(prog))
+            setup_span.set_attrs(aot=_aot.status(prog), loop=prog.loop)
         # host scalars travel with the execute call — no extra device
         # round-trips.
         # Tolerances are always REAL-typed: for complex operators the

@@ -200,15 +200,18 @@ def _ksp_case(case, monkeypatch):
     """``(operator, ksp_type, pc_type)`` of one cell-like solve, small."""
     from mpi_petsc4py_example_tpu.models import StencilPoisson3D, convdiff2d
     comm = tps.DeviceComm(n_devices=1)
-    if case == "bcgs_bjacobi_ilu0":
-        from mpi_petsc4py_example_tpu.solvers import bjilu
+    if case.startswith("bcgs_bjacobi"):
+        from mpi_petsc4py_example_tpu.solvers import bcgs_dd, bjilu
         from mpi_petsc4py_example_tpu.solvers import pc as pcmod
         # past a lowered dense cap: 16 ILU(0) blocks of 2 lines, the XLA
-        # sweeps (the TPU kernel's layout is the chip's)
+        # sweeps (the TPU kernel's layout is the chip's); "dd": the TPU
+        # kernel's stack and the double-f32 loop, in interpret mode
+        dd = case == "bcgs_bjacobi_dd"
         monkeypatch.setattr(pcmod, "_DENSE_CAP", 64)
         monkeypatch.setattr(bjilu, "BLOCK_ROWS", 64)
-        monkeypatch.setattr(bjilu, "use_kernel", lambda platform, dtype:
-                            False)
+        monkeypatch.setattr(bjilu, "use_kernel", lambda platform, dtype: dd)
+        if dd:
+            monkeypatch.setattr(bcgs_dd, "PLATFORMS", ("tpu", "cpu"))
         return tps.Mat.from_scipy(comm, convdiff2d(32).tocsr()), "bcgs", \
             "bjacobi"
     return StencilPoisson3D(comm, 16, 16, 16), "cg", case.split("_")[1]
@@ -234,9 +237,30 @@ def _ksp_solve(op, ksp_type, pc_type, guess):
     return x.to_numpy(), int(res.iterations), ksp.get_pc().kind
 
 
-def _builds(spans):
-    return [s["attrs"]["aot"] for s in spans
+def _builds(spans, attr="aot"):
+    return [s["attrs"][attr] for s in spans
             if s["name"] == "ksp.setup" and "aot" in s["attrs"]]
+
+
+def _cold_then_warm(case, guess, spans, monkeypatch):
+    """One solve with no blob, then one in a fresh program cache: ``(x,
+    iterations, PC kind)`` of each, and each one's spans."""
+    from mpi_petsc4py_example_tpu.solvers import krylov
+    op, ksp_type, pc_type = _ksp_case(case, monkeypatch)
+    krylov._PROGRAM_CACHE.clear()
+    n0 = len(spans())
+    cold_run = _ksp_solve(op, ksp_type, pc_type, guess)
+    cold = spans()[n0:]
+    krylov._PROGRAM_CACHE.clear()
+    warm_run = _ksp_solve(op, ksp_type, pc_type, guess)
+    warm = spans()[n0 + len(cold):]
+    krylov._PROGRAM_CACHE.clear()
+    return cold_run, cold, warm_run, warm
+
+
+def _traced(spans):
+    return {s["attrs"].get("fun_name", "") for s in spans
+            if s["name"] == "compile.trace"}
 
 
 class TestKspProgram:
@@ -272,6 +296,31 @@ class TestKspProgram:
                   if s["name"] == "compile.trace"]
         assert not [f for f in traced if "local_fn" in f], traced
         krylov._PROGRAM_CACHE.clear()
+
+    @pytest.mark.parametrize("guess", ["zero", "nonzero"])
+    def test_double_f32_bcgs_export_then_load(self, guess, aot_dir, spans,
+                                              monkeypatch):
+        """fp64 BCGS's double-f32 loop (solvers/bcgs_dd.py) exports and
+        loads like the XLA loop: the warm process loads, gives the same x
+        bit for bit and the same iterations, traces no solve body and no
+        function the XLA loop's warm process does not, and its program
+        build says which loop it holds."""
+        (x1, it1, kind), cold, (x2, it2, _), warm = _cold_then_warm(
+            "bcgs_bjacobi_dd", guess, spans, monkeypatch)
+        assert kind == "bjacobi_ilu0"
+        assert set(_builds(cold)) == {"miss"}
+        assert set(_builds(warm)) == {"hit"}
+        assert set(_builds(cold, "loop")) == set(_builds(warm, "loop")) \
+            == {"dd_pallas"}
+        assert any("local_fn" in f for f in _traced(cold))
+        assert it2 == it1
+        np.testing.assert_array_equal(x2, x1)
+        assert not [f for f in _traced(warm) if "local_fn" in f]
+        *_, xla_warm = _cold_then_warm("bcgs_bjacobi_ilu0", guess, spans,
+                                       monkeypatch)
+        assert set(_builds(xla_warm, "loop")) == {"xla"}
+        assert _traced(warm) <= _traced(xla_warm), \
+            _traced(warm) - _traced(xla_warm)
 
     @pytest.mark.parametrize("local", ["shell_pc", "live_monitor",
                                        "fault_plan"])
@@ -330,7 +379,8 @@ class TestKspProgram:
 @pytest.mark.parametrize("module", [
     "solvers/krylov.py", "solvers/cg_plans.py", "solvers/pc.py",
     "solvers/bjilu.py", "solvers/mg.py", "ops/pallas_stencil.py",
-    "core/mat.py", "models/stencil.py"])
+    "core/mat.py", "models/stencil.py", "solvers/bcgs_dd.py",
+    "ops/double_f32.py"])
 def test_package_fingerprint_sees_every_module(module, tmp_path):
     """One byte changed in any module a solve program traces changes the
     fingerprint every blob key holds: the edit misses the cache."""

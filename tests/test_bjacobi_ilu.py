@@ -13,7 +13,8 @@ Pins, in fp64 at small sizes with the caps lowered:
   block count that fits it, a non-five-point pattern, a coupling across
   a line end or a complex operator; ILU(0) on a real five-point DIA
   operator past it, of the explicit count where one is given;
-* BCGS and BiCG + bjacobi through KSP, and CG + bjacobi through
+* BCGS (its XLA loop and its double-f32 one, solvers/bcgs_dd.py) and
+  BiCG + bjacobi through KSP, and CG + bjacobi through
   ``KSP.solve_many``, against ``scipy.sparse.linalg.spsolve``;
 * the ``mat.assemble`` and ``pc.setup`` spans that say what ran.
 """
@@ -233,26 +234,41 @@ def test_path_selection(comm1, monkeypatch, case, want):
     assert _picked(comm1, A, monkeypatch, **kw) == want
 
 
-@pytest.mark.parametrize("apply", ["xla", "pallas"])
+@pytest.mark.parametrize("apply", ["xla", "pallas", "dd"])
 @pytest.mark.parametrize("ndev", [1, 8])
 def test_bcgs_bjacobi_matches_spsolve(monkeypatch, ndev, apply):
     """16 ILU(0) blocks of 2 lines; sharded, two on each of 8 devices;
-    the XLA sweeps and the TPU kernel's (interpret mode)."""
-    x, want, pc = _ksp_past_cap(monkeypatch, ndev, apply, "bcgs")
+    the XLA sweeps and the TPU kernel's (interpret mode); "dd": the TPU
+    kernel's, with the loop as double-f32 sweeps (solvers/bcgs_dd.py)
+    where it engages, one device, within 2 iterations of the XLA
+    loop's."""
+    from mpi_petsc4py_example_tpu.solvers import bcgs_dd, krylov
+    if apply == "dd":
+        monkeypatch.setattr(bcgs_dd, "PLATFORMS", ("tpu", "cpu"))
+    krylov._PROGRAM_CACHE.clear()
+    x, want, pc, its = _ksp_past_cap(monkeypatch, ndev, apply, "bcgs")
+    loops = {p.loop for p in krylov._PROGRAM_CACHE.values()}
+    krylov._PROGRAM_CACHE.clear()
+    assert loops == {"dd_pallas" if (apply, ndev) == ("dd", 1) else "xla"}
     assert (pc.sub_solve, pc.sub_blocks) == ("ilu0", 16)
-    assert pc.setup_breakdown["apply"] == apply
+    assert pc.setup_breakdown["apply"] == ("pallas" if apply == "dd"
+                                           else apply)
     err = np.linalg.norm(x - want) / np.linalg.norm(want)
     assert err < 1e-9, err
+    if apply == "dd":
+        monkeypatch.setattr(bcgs_dd, "PLATFORMS", ())
+        *_, its_xla = _ksp_past_cap(monkeypatch, ndev, apply, "bcgs")
+        assert abs(its - its_xla) <= 2, (its, its_xla)
 
 
 def _ksp_past_cap(monkeypatch, ndev, apply, ksp_type, nrhs=0, beta=0.3):
     """A KSP solve of ``convdiff2d(32)`` with bjacobi past a lowered dense
     cap (16 ILU(0) blocks of 2 lines), one right-hand side or ``nrhs``
-    through ``solve_many``; returns (x, scipy's x, the PC)."""
+    through ``solve_many``; returns (x, scipy's x, the PC, iterations)."""
     monkeypatch.setattr(pcmod, "_DENSE_CAP", 64)
     monkeypatch.setattr(bjilu, "BLOCK_ROWS", 64)
     monkeypatch.setattr(bjilu, "use_kernel",
-                        lambda platform, dtype: apply == "pallas")
+                        lambda platform, dtype: apply in ("pallas", "dd"))
     comm = tps.DeviceComm(n_devices=ndev)
     A = convdiff2d(32, beta=beta).tocsr()
     X = np.random.default_rng(5).random((A.shape[0], max(nrhs, 1)))
@@ -267,18 +283,19 @@ def _ksp_past_cap(monkeypatch, ndev, apply, ksp_type, nrhs=0, beta=0.3):
     if nrhs:
         res = ksp.solve_many(B)
         assert res.converged
-        return np.asarray(res.X), want, ksp.get_pc()
+        return np.asarray(res.X), want, ksp.get_pc(), None
     x, bv = M.get_vecs()
     bv.set_global(B[:, 0])
-    assert ksp.solve(bv, x).converged
-    return x.to_numpy(), want[:, 0], ksp.get_pc()
+    res = ksp.solve(bv, x)
+    assert res.converged
+    return x.to_numpy(), want[:, 0], ksp.get_pc(), int(res.iterations)
 
 
 @pytest.mark.parametrize("apply", ["xla", "pallas"])
 @pytest.mark.parametrize("ndev", [1, 8])
 def test_bicg_bjacobi_past_cap(monkeypatch, ndev, apply):
     """BiCG's shadow recurrence applies the ILU(0) blocks' transpose."""
-    x, want, pc = _ksp_past_cap(monkeypatch, ndev, apply, "bicg")
+    x, want, pc, _ = _ksp_past_cap(monkeypatch, ndev, apply, "bicg")
     assert pc.kind == "bjacobi_ilu0"
     err = np.linalg.norm(x - want) / np.linalg.norm(want)
     assert err < 1e-9, err
@@ -291,7 +308,7 @@ def test_solve_many_bjacobi_past_cap(monkeypatch, ndev, apply):
     the batched program, not one solve a column (the five-point
     Laplacian, whose ILU(0) is symmetric)."""
     monkeypatch.setattr(tps.KSP, "_solve_many_sequential", None)
-    X, want, pc = _ksp_past_cap(monkeypatch, ndev, apply, "cg", nrhs=3,
+    X, want, pc, _ = _ksp_past_cap(monkeypatch, ndev, apply, "cg", nrhs=3,
                                 beta=0.0)
     assert pc.kind == "bjacobi_ilu0"
     err = np.linalg.norm(X - want) / np.linalg.norm(want)

@@ -221,3 +221,48 @@ def test_solve_program_exports(topo, monkeypatch, pc_type):
     with aot._this_runtime():
         exported = jax.export.export(prog.traced, platforms=["tpu"])(*args)
     assert "tpu_custom_call" in exported.mlir_module()
+
+
+@pytest.mark.parametrize("pc_type", ["bjacobi"])
+def test_bcgs_double_f32_program_2048(topo, one_chip, pc_type):
+    """The fp64 BCGS solve program with its double-f32 loop
+    (solvers/bcgs_dd.py) at 2048^2 on a five-point DIA operator with 64
+    ILU(0) blocks in 8 groups: Mosaic accepts every sweep within its
+    VMEM limit and the program fits one chip."""
+    import mpi_petsc4py_example_tpu as tps
+    from mpi_petsc4py_example_tpu.parallel.mesh import DeviceComm
+    from mpi_petsc4py_example_tpu.solvers import krylov
+
+    comm = DeviceComm(devices=[topo.devices[0]])
+    nx, F64 = 2048, jnp.float64
+    n = nx * nx
+
+    def placed(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=comm.row_sharding)
+
+    # described devices hold no arrays: the operator and the PC carry
+    # their arrays' shapes
+    op = tps.Mat(comm, (n, n), placed((n, 5), jnp.int32), placed((n, 5), F64))
+    op.dia_vals, op.dia_offsets = placed((n, 5), F64), (-nx, -1, 0, 1, nx)
+    pc = tps.PC(comm)
+    pc.set_type(pc_type)
+    pc.sub_solve = "ilu0"
+    pc._arrays = (placed((8, 10, 32, 8, nx), F32),)
+    krylov._PROGRAM_CACHE.clear()
+    prog = krylov.build_ksp_program(comm, "bcgs", pc, op, zero_guess=True,
+                                    true_res=True, donate=True)
+    krylov._PROGRAM_CACHE.clear()
+    assert prog.loop == "dd_pallas"
+    v = placed((n,), F64)
+    args = (op.device_arrays(), pc.device_arrays(), v, v, np.float64(1e-8),
+            np.float64(0), np.float64(0), np.int32(10))
+    compiled = getattr(prog, "traced", prog).lower(*args).compile()
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert used < HBM_BYTES, used
+    text = compiled.as_text()
+    for name in ("bcgs_dd_spmv_pallas", "bcgs_dd_update_pallas",
+                 "bjacobi_ilu0_pallas"):
+        assert f"%{name}" in text, name
